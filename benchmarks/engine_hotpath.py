@@ -268,7 +268,10 @@ def main() -> int:
     import jax
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import init_params
+
+    enable_compile_cache()
 
     if mesh_shape is not None:
         # validate BEFORE any measurement so a short device count can't

@@ -8,10 +8,15 @@ cache exactly once, amortized over t query rows.  The TPU-native
 translation is a kernel whose HBM traffic is exactly one streaming pass
 over the KV cache, with the online softmax state held in VMEM:
 
-  grid = (batch, kv_heads, S // block_k)   last axis innermost/sequential
-  K/V blocks:  [block_k, hd]   streamed HBM -> VMEM once
-  Q block:     [R, hd]         R = q_rows * g query rows pinned per (b, h)
-  scratch:     acc [R, hd] f32, m/l [R, 128] f32 running softmax state
+  grid = (batch, S // block_k)             last axis innermost/sequential
+  K/V blocks:  [block_k, nkv, hd]   streamed HBM -> VMEM once
+  Q block:     [nkv, R, hd]         R = q_rows * g query rows per KV head
+  scratch:     acc [nkv, R, hd] f32, m/l [nkv, R, 128] f32 softmax state
+
+In VMEM each KV position's (nkv, hd) slice of a K/V block fills a whole
+(sublane, 128-lane) tile, so a block costs about block_k * 4 KiB per
+buffer; the default block_k of 256 keeps the four double-buffered K/V
+blocks near 4 MiB.
 
 Query windows (TLP > 1)
 -----------------------
@@ -62,23 +67,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
 
 def _kernel(
     lens_ref,      # SMEM [b] int32 — scalar-prefetched per-request lengths
-    q_ref,         # [1, 1, R, hd]   R = q_rows * g, (window, group)-row-major
-    k_ref,         # [1, block_k, 1, hd]
-    v_ref,         # [1, block_k, 1, hd]
-    o_ref,         # [1, 1, R, hd]
-    acc_ref,       # VMEM [R, hd] f32
-    m_ref,         # VMEM [R, 128] f32 (lane-padded running max)
-    l_ref,         # VMEM [R, 128] f32 (lane-padded running sum)
+    q_ref,         # [nkv, R, hd]   R = q_rows * g, (window, group)-row-major
+    k_ref,         # [block_k, nkv, hd]
+    v_ref,         # [block_k, nkv, hd]
+    o_ref,         # [nkv, R, hd]
+    acc_ref,       # VMEM [nkv, R, hd] f32
+    m_ref,         # VMEM [nkv, R, 128] f32 (lane-padded running max)
+    l_ref,         # VMEM [nkv, R, 128] f32 (lane-padded running sum)
     *,
     block_k: int,
     num_kb: int,
@@ -86,7 +88,7 @@ def _kernel(
     q_rows: int = 1,
 ):
     i = pl.program_id(0)
-    kb = pl.program_id(2)
+    kb = pl.program_id(1)
     length = lens_ref[i]
 
     @pl.when(kb == 0)
@@ -95,10 +97,10 @@ def _kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _compute():
-        q = q_ref[0, 0]                                   # [g, hd]
-        k = k_ref[0, :, 0]                                # [block_k, hd]
-        v = v_ref[0, :, 0]                                # [block_k, hd]
+    def _head(h):
+        q = q_ref[h]                                      # [g, hd]
+        k = k_ref[:, h, :]                                # [block_k, hd]
+        v = v_ref[:, h, :]                                # [block_k, hd]
         scale = 1.0 / math.sqrt(q.shape[-1])
 
         s = jax.lax.dot_general(
@@ -119,20 +121,26 @@ def _kernel(
             limit = length - (q_rows - 1) + row
         s = jnp.where(kv_pos < limit, s, NEG_INF)
 
-        m_prev = m_ref[:, 0:1]                            # [g, 1]
+        m_prev = m_ref[h, :, 0:1]                         # [g, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)        # [g, 1]
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)                            # [g, block_k]
         alpha = jnp.exp(m_prev - m_new)                   # [g, 1]
 
-        l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = l_ref[h, :, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                                 # [g, hd]
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        acc_ref[h] = acc_ref[h] * alpha + pv
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    def _compute():
+        # the KV heads share one block (see `decode_attention`); each runs
+        # its own online softmax over its g query rows
+        for h in range(q_ref.shape[0]):
+            _head(h)
 
     if block_skip:
         # fully-masked tile => no-op (the DMA was already elided by the
@@ -143,8 +151,8 @@ def _kernel(
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
-        l = l_ref[:, 0:1]
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        l = l_ref[:, :, 0:1]
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -155,7 +163,7 @@ def decode_attention(
     v_cache: jax.Array,    # [b, S, nkv, hd]
     lens: jax.Array,       # [b] int32 valid lengths (ALL q_rows included)
     *,
-    block_k: int = 512,
+    block_k: int = 256,
     interpret: bool | None = None,
     block_skip: bool = True,
     q_rows: int = 1,
@@ -170,41 +178,47 @@ def decode_attention(
     num_kb = skv // block_k
     lens1 = lens.astype(jnp.int32).reshape(b)
 
-    def q_index(i, j, kb, lens_ref):
-        return (i, j, 0, 0)
+    def q_index(i, kb, lens_ref):
+        return (i, 0, 0, 0)
 
-    def kv_index(i, j, kb, lens_ref):
+    def kv_index(i, kb, lens_ref):
         if not block_skip:
-            return (i, kb, j, 0)
+            return (i, kb, 0, 0)
         # clamp to the request's last valid block: repeated indices make the
         # pipeline skip the redundant fetch for fully-masked tiles
         last = jnp.maximum(pl.cdiv(lens_ref[i], block_k) - 1, 0)
-        return (i, jnp.minimum(kb, last), j, 0)
+        return (i, jnp.minimum(kb, last), 0, 0)
 
-    grid = (b, nkv, num_kb)
+    # A grid step holds every KV head of one (request, KV block), batch dim
+    # squeezed: the q/out block is [nkv, R, hd] and the K/V block
+    # [block_k, nkv, hd].  Mosaic wants a block's last two dims (8, 128)-
+    # aligned or whole; these are whole, where a per-head K/V block's size-1
+    # head dim would be refused.
+    grid = (b, num_kb)
     kernel = functools.partial(_kernel, block_k=block_k, num_kb=num_kb,
                                block_skip=block_skip, q_rows=q_rows)
+    sq = pl.Squeezed()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), q_index),
-            pl.BlockSpec((1, block_k, 1, hd), kv_index),
-            pl.BlockSpec((1, block_k, 1, hd), kv_index),
+            pl.BlockSpec((sq, nkv, g, hd), q_index),
+            pl.BlockSpec((sq, block_k, nkv, hd), kv_index),
+            pl.BlockSpec((sq, block_k, nkv, hd), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), q_index),
+        out_specs=pl.BlockSpec((sq, nkv, g, hd), q_index),
         scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
+            pltpu.VMEM((nkv, g, hd), jnp.float32),
+            pltpu.VMEM((nkv, g, 128), jnp.float32),
+            pltpu.VMEM((nkv, g, 128), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="papi_decode_attention",
@@ -219,7 +233,7 @@ def decode_attention_sharded(
     *,
     mesh,
     axis: str = "model",
-    block_k: int = 512,
+    block_k: int = 256,
     interpret: bool | None = None,
     block_skip: bool = True,
     q_rows: int = 1,
@@ -248,11 +262,11 @@ def decode_attention_sharded(
     kernel = functools.partial(decode_attention, block_k=block_k,
                                interpret=interpret, block_skip=block_skip,
                                q_rows=q_rows)
-    return shard_map(
+    return jax.shard_map(
         lambda qs, ks, vs, ls: kernel(qs, ks, vs, ls),
         mesh=mesh,
         in_specs=(P(None, axis), P(None, None, axis), P(None, None, axis),
                   P()),
         out_specs=P(None, axis),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, lens)

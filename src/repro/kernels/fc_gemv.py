@@ -12,9 +12,10 @@ block pinned in VMEM for the whole kernel:
   acc:     [m, block_n] f32 scratch
 
 Block tuning: unless the caller pins block sizes, `_auto_blocks` picks the
-largest divisors of (K, N) whose *double-buffered* working set fits a
-conservative VMEM budget — the pipeline overlaps the next weight tile's DMA
-with the current tile's FLOPs, so both buffers must be resident at once.
+largest Mosaic-legal blocks of (K, N) whose *double-buffered* working set
+fits a conservative VMEM budget — the pipeline overlaps the next weight
+tile's DMA with the current tile's FLOPs, so both buffers must be resident
+at once.
 Bigger tiles amortize grid/DMA overhead; the budget keeps two w-tiles, two
 x-tiles, the f32 accumulator and the output block co-resident.
 
@@ -31,19 +32,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 # Conservative per-core VMEM budget for the kernel's working set (real VMEM
 # is ~16 MiB; leave headroom for the pipeline's own bookkeeping).
 _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
-def _largest_divisor(dim: int, target: int) -> int:
-    """Largest divisor of dim that is <= target."""
-    b = min(dim, target)
-    while dim % b:
-        b -= 1
-    return b
+# Mosaic tiles a block's last two dims by (sublanes, 128 lanes): each must
+# be a multiple of its tile or the full array dim.  K is the lane dim of the
+# x block and the second-minor dim of the w block, N the lane dim of the w
+# and output blocks, so both are held to the lane tile (which is also a
+# multiple of every dtype's sublane packing).
+_LANE = 128
+
+
+def _legal_blocks(dim: int) -> list[int]:
+    """Block sizes Mosaic accepts for `dim`, largest first: the full dim and
+    every divisor that is a multiple of the lane tile."""
+    return [dim] + [b for b in range((dim - 1) // _LANE * _LANE, 0, -_LANE)
+                    if dim % b == 0]
+
+
+def _pick(dim: int, target: int) -> int:
+    """Largest legal block of `dim` that is <= target, else the smallest."""
+    legal = _legal_blocks(dim)
+    return next((b for b in legal if b <= target), legal[-1])
 
 
 def _working_set_bytes(m: int, bk: int, bn: int, itemsize: int) -> int:
@@ -56,13 +68,14 @@ def _working_set_bytes(m: int, bk: int, bn: int, itemsize: int) -> int:
 
 
 def _auto_blocks(m: int, K: int, N: int, itemsize: int) -> tuple[int, int]:
-    """Pick (block_k, block_n) fitting the double-buffered VMEM budget."""
-    for target in (1024, 768, 512, 384, 256, 128, 64, 32, 16, 8):
-        bk = _largest_divisor(K, target)
-        bn = _largest_divisor(N, target)
+    """Pick Mosaic-legal (block_k, block_n) fitting the double-buffered
+    VMEM budget; a dim with no legal block under the target (not a multiple
+    of 128) keeps its full size."""
+    for target in (1024, 768, 512, 384, 256, 128):
+        bk, bn = _pick(K, target), _pick(N, target)
         if _working_set_bytes(m, bk, bn, itemsize) <= _VMEM_BUDGET_BYTES:
             return bk, bn
-    return _largest_divisor(K, 8), _largest_divisor(N, 8)
+    return bk, bn
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref, *, num_kb: int):
@@ -114,7 +127,7 @@ def fc_gemv(
         out_specs=pl.BlockSpec((m, block_n), lambda n, k: (0, n)),
         out_shape=jax.ShapeDtypeStruct((m, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((m, block_n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # n parallel, k sequential: the k accumulation must stay ordered,
             # the n tiles are independent so the pipeline can double-buffer
             # the weight stream across both axes.

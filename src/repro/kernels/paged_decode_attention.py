@@ -7,7 +7,7 @@ Attn-PIM bank row, see `serving/kv_pages.py`) and a per-request block table
 maps logical KV blocks to physical pages.  This kernel runs the SAME online
 softmax over that layout:
 
-  grid = (batch, kv_heads, max_blocks)    last axis innermost/sequential
+  grid = (batch, max_blocks)              last axis innermost/sequential
   scalar prefetch:  lens   [b]            per-request valid lengths
                     tables [b, max_blocks] logical block -> physical page
 
@@ -54,10 +54,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.decode_attention import _kernel
 
 
@@ -94,42 +92,44 @@ def paged_decode_attention(
     lens1 = lens.astype(jnp.int32).reshape(b)
     tables1 = tables.astype(jnp.int32).reshape(b, num_blocks)
 
-    def q_index(i, j, kb, lens_ref, tables_ref):
-        return (i, j, 0, 0)
+    def q_index(i, kb, lens_ref, tables_ref):
+        return (i, 0, 0, 0)
 
-    def kv_index(i, j, kb, lens_ref, tables_ref):
+    def kv_index(i, kb, lens_ref, tables_ref):
         if block_skip:
             # clamp to the request's last valid logical block; repeated
             # physical indices let the pipeline skip the redundant fetch
             last = jnp.maximum(pl.cdiv(lens_ref[i], page_size) - 1, 0)
             kb = jnp.minimum(kb, last)
-        return (tables_ref[i, kb], 0, j, 0)
+        return (tables_ref[i, kb], 0, 0, 0)
 
-    grid = (b, nkv, num_blocks)
+    # blocks as in the dense kernel: all KV heads of one page per grid step
+    grid = (b, num_blocks)
     kernel = functools.partial(_paged_kernel, page_size=page_size,
                                num_blocks=num_blocks, block_skip=block_skip,
                                q_rows=q_rows)
+    sq = pl.Squeezed()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), q_index),
-            pl.BlockSpec((1, page_size, 1, hd), kv_index),
-            pl.BlockSpec((1, page_size, 1, hd), kv_index),
+            pl.BlockSpec((sq, nkv, g, hd), q_index),
+            pl.BlockSpec((sq, page_size, nkv, hd), kv_index),
+            pl.BlockSpec((sq, page_size, nkv, hd), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), q_index),
+        out_specs=pl.BlockSpec((sq, nkv, g, hd), q_index),
         scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
+            pltpu.VMEM((nkv, g, hd), jnp.float32),
+            pltpu.VMEM((nkv, g, 128), jnp.float32),
+            pltpu.VMEM((nkv, g, 128), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="papi_paged_decode_attention",
@@ -168,11 +168,11 @@ def paged_decode_attention_sharded(
                                       block_skip=block_skip, q_rows=q_rows)
     kernel = functools.partial(paged_decode_attention, interpret=interpret,
                                block_skip=block_skip, q_rows=q_rows)
-    return shard_map(
+    return jax.shard_map(
         lambda qs, ks, vs, ls, ts: kernel(qs, ks, vs, ls, ts),
         mesh=mesh,
         in_specs=(P(None, axis), P(None, None, axis), P(None, None, axis),
                   P(), P()),
         out_specs=P(None, axis),
-        check_rep=False,
+        check_vma=False,
     )(q, k_pages, v_pages, lens, tables)

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 
 def _kernel(
@@ -112,7 +111,7 @@ def ssd_scan(
         out_specs=pl.BlockSpec((1, 1, 1, cs, hp), lambda i, h, c: (i, h, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, nh, nc, cs, hp), dtx.dtype),
         scratch_shapes=[pltpu.VMEM((hp, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

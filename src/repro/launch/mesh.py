@@ -9,6 +9,15 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with `Auto` axes.  The model code places arrays with
+    `with_sharding_constraint` and leaves the rest to GSPMD, which only Auto
+    axes allow (make_mesh's default is Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,14 +26,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     carries data parallelism across the DCN/ICI-superpod boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices this host actually has, as a 1D data mesh — used by
     the runnable examples on CPU."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return auto_mesh((n,), ("data",))
 
 
 def make_serving_mesh(dp: int = 1, tp: int = 1):
@@ -32,7 +41,7 @@ def make_serving_mesh(dp: int = 1, tp: int = 1):
     FC weights split into one FC-PIM bank per shard and the KV cache slices
     one Attn-PIM unit per shard (§5.3); `data` replicates the engine for
     throughput.  Uses the first dp*tp devices."""
-    return jax.make_mesh((dp, tp), ("data", "model"), devices=jax.devices()[: dp * tp])
+    return auto_mesh((dp, tp), ("data", "model"), jax.devices()[: dp * tp])
 
 
 def parse_mesh(spec: str) -> tuple[int, int]:

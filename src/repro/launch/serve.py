@@ -187,12 +187,14 @@ def main() -> None:
 
     from repro.configs import get_config
     from repro.core.traces import generate_trace
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_serving_mesh
     from repro.models import init_params
     from repro.serving import (EngineCrashError, PapiEngine, ServeRequest,
                                Tracer, export_prometheus, parse_fault_specs,
                                write_trace)
 
+    enable_compile_cache()
     mesh = None
     if mesh_shape is not None:
         dp, tp = mesh_shape

@@ -46,7 +46,6 @@ import threading
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import fc_tensor_axis
@@ -91,10 +90,10 @@ def _pim_gemv(x2: jax.Array, w: jax.Array, tp: str | None, bank: str,
         if tp == "col" and n % size == 0:
             # output-dim banks: every shard produces its own slice, no
             # collective (QKV / gate / up projections)
-            return shard_map(
+            return jax.shard_map(
                 lambda xs, ws: fc_gemv(xs, ws, interpret=interpret),
                 mesh=mesh, in_specs=(P(), P(None, axis)),
-                out_specs=P(None, axis), check_rep=False,
+                out_specs=P(None, axis), check_vma=False,
             )(x2, w)
         if tp == "row" and k % size == 0:
             # contraction-dim banks: shards hold partial products, reduced
@@ -102,9 +101,9 @@ def _pim_gemv(x2: jax.Array, w: jax.Array, tp: str | None, bank: str,
             def _row(xs, ws):
                 return jax.lax.psum(fc_gemv(xs, ws, interpret=interpret),
                                     axis)
-            return shard_map(
+            return jax.shard_map(
                 _row, mesh=mesh, in_specs=(P(None, axis), P(axis, None)),
-                out_specs=P(), check_rep=False,
+                out_specs=P(), check_vma=False,
             )(x2, w)
     return fc_gemv(x2, w, interpret=interpret)
 

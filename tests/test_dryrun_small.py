@@ -4,6 +4,8 @@ full lower+compile through the real build_step/dryrun path.
 Runs in a subprocess because XLA_FLAGS must be set before jax initializes
 (the main pytest process keeps its single CPU device)."""
 import json
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -18,8 +20,9 @@ from repro.configs import get_config, SHAPES
 from repro.configs.base import ShapeCell
 from repro.launch.steps import build_step, lower_step
 from repro.launch.hlo import HLOAnalysis
+from repro.launch.mesh import auto_mesh
 
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = auto_mesh((4, 4), ("data", "model"))
 out = {}
 for arch, kind in [("qwen2-0.5b", "train"), ("mamba2-1.3b", "decode"),
                    ("olmoe-1b-7b", "prefill")]:
@@ -40,12 +43,12 @@ print(json.dumps(out))
 
 @pytest.mark.slow
 def test_small_mesh_dryrun_all_kinds():
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(PYTHONPATH=str(repo / "src"), JAX_PLATFORMS="cpu")
     res = subprocess.run(
         [sys.executable, "-c", SCRIPT],
-        capture_output=True, text=True, timeout=540,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"},
-        cwd="/root/repo",
+        capture_output=True, text=True, timeout=540, env=env, cwd=repo,
     )
     assert res.returncode == 0, res.stderr[-3000:]
     out = json.loads(res.stdout.strip().splitlines()[-1])

@@ -294,16 +294,15 @@ def test_paged_decode_attention_sharded_bit_identical():
 def test_sharded_fc_gemv_col_banks_bit_identical():
     """Column-split FC-PIM banks concatenate without any cross-bank
     reduction — bit-identical to the single-bank kernel."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels import fc_gemv
     x = jax.random.normal(jax.random.PRNGKey(2), (4, 128), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(3), (128, 256), jnp.float32)
     mesh = _mesh(1, 8)
-    got = shard_map(lambda xs, ws: fc_gemv(xs, ws, interpret=True),
-                    mesh=mesh, in_specs=(P(), P(None, "model")),
-                    out_specs=P(None, "model"), check_rep=False)(x, w)
+    got = jax.shard_map(lambda xs, ws: fc_gemv(xs, ws, interpret=True),
+                        mesh=mesh, in_specs=(P(), P(None, "model")),
+                        out_specs=P(None, "model"), check_vma=False)(x, w)
     want = fc_gemv(x, w, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
