@@ -642,8 +642,8 @@ def main() -> int:
                   f"{same}")
 
         # Telemetry overhead A/B: the SAME spec_dense arrival trace once
-        # more, now with a live Tracer (per-program timed_call blocks on
-        # every dispatch), against the untraced run already measured above.
+        # more, now with a live Tracer (typed events and phase spans, no
+        # blocking), against the untraced run already measured above.
         # Identity proves observation never perturbs tokens; the median
         # per-iteration wall ratio is the overhead check_bench gates.
         telemetry = None
@@ -690,15 +690,15 @@ def main() -> int:
                 "tokens_bit_identical": t_same,
                 "events": tracer.emitted,
                 "events_dropped": tracer.dropped,
-                "program_keys": len(tracer.programs),
+                "phase_spans": tracer.counters.get("phase", 0),
                 "trace_file": str(args.trace),
             }
             all_ok = all_ok and t_same
             print(f"telemetry: {un_tps:.1f} tok/s untraced vs "
                   f"{tr_tps:.1f} tok/s traced (median-wall overhead "
                   f"{telemetry['overhead_frac']:+.1%}), {tracer.emitted} "
-                  f"events, {len(tracer.programs)} program keys, tokens "
-                  f"identical: {t_same}")
+                  f"events, {tracer.counters.get('phase', 0)} phase spans, "
+                  f"tokens identical: {t_same}")
             print(f"wrote {args.trace}")
 
         out = Path(args.out)

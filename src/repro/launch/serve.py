@@ -126,10 +126,10 @@ def main() -> None:
                          "(seed, iteration), so runs replay exactly)")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="record the engine's typed event trace and write "
-                         "it to PATH on exit — iteration spans, scheduler "
-                         "decisions (AI estimate vs alpha), per-program "
-                         "timings by jit-cache key, preemptions/deferrals/"
-                         "faults, page-pool occupancy.  Summarize with "
+                         "it to PATH on exit — iteration and phase spans, "
+                         "scheduler decisions (AI estimate vs alpha), "
+                         "preemptions/deferrals/faults, page-pool "
+                         "occupancy.  Summarize with "
                          "tools/trace_report.py")
     ap.add_argument("--trace-format", choices=("chrome", "jsonl"),
                     default="chrome",
@@ -355,11 +355,9 @@ def _report(args, eng, results, tracer) -> None:
             from pathlib import Path
             Path(args.metrics_out).write_text(export_prometheus(tracer))
         c = tracer.counters
-        prog_s = sum(t.total_s for t in tracer.programs.values())
         print(f"\ntelemetry: {tracer.emitted} events "
               f"({tracer.dropped} dropped), {c.get('scheduler_flip', 0)} "
-              f"scheduler flips, {len(tracer.programs)} program keys "
-              f"({prog_s:.2f}s on device)"
+              f"scheduler flips, {c.get('phase', 0)} phase spans"
               + (f" -> {args.trace}" if args.trace else "")
               + (f", metrics -> {args.metrics_out}"
                  if args.metrics_out else ""))

@@ -9,18 +9,16 @@ from repro.serving.kv_pages import (BlockTables, PageAllocator, PagedKVManager,
                                     PageStats)
 from repro.serving.metrics import latency_summary, percentile
 from repro.serving.sampler import greedy, sample
-from repro.serving.telemetry import (NULL_TRACER, Event, NullTracer,
-                                     ProgramTiming, Tracer, export_chrome,
-                                     export_jsonl, export_prometheus,
-                                     write_trace)
+from repro.serving.telemetry import (NULL_TRACER, Event, NullTracer, Tracer,
+                                     export_chrome, export_jsonl,
+                                     export_prometheus, phase, write_trace)
 
 __all__ = ["AllocatorInvariantError", "BlockTables", "EngineCrashError",
            "EngineStallError", "Event", "FaultInjector", "FinishedRequest",
            "IterStats", "Journal", "NULL_TRACER", "NullTracer",
            "PageAllocator", "PagedKVManager", "PageStats", "PapiEngine",
-           "ProgramTiming", "RecoveredRequest", "RecoveredState",
-           "ServeRequest", "ServeResult", "TokenEvent", "Tracer",
-           "export_chrome", "export_jsonl", "export_prometheus", "greedy",
-           "latency_summary", "parse_fault_specs", "percentile",
-           "read_records", "recover", "replay", "sample", "write_snapshot",
-           "write_trace"]
+           "RecoveredRequest", "RecoveredState", "ServeRequest",
+           "ServeResult", "TokenEvent", "Tracer", "export_chrome",
+           "export_jsonl", "export_prometheus", "greedy", "latency_summary",
+           "parse_fault_specs", "percentile", "phase", "read_records",
+           "recover", "replay", "sample", "write_snapshot", "write_trace"]

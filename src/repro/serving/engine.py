@@ -170,6 +170,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import logging
 import time
 from functools import partial
@@ -194,7 +195,8 @@ from repro.serving.journal import (SNAPSHOT_VERSION, Journal, recover,
                                    write_snapshot)
 from repro.serving.kv_pages import PagedKVManager
 from repro.serving.sampler import accept_speculative, greedy
-from repro.serving.telemetry import NULL_TRACER, Tracer
+from repro.serving.telemetry import (NULL_TRACER, Tracer, format_program_key,
+                                     phase)
 
 # the serving subsystem's logger: deferral (DEBUG), preemption / unhappy
 # finishes (INFO), degraded re-runs (WARNING), stalls (ERROR).  Unconfigured
@@ -298,6 +300,16 @@ class AllocatorInvariantError(RuntimeError):
         self.snapshot = snapshot
 
 
+def _named(name: str, fn):
+    """``fn`` under the function name ``name``, so that its jitted program
+    is ``jit_<name>`` in the profiler's trace (a `partial` has none)."""
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 def _inject_fault(logits, code):
     """Apply the iteration's fault code (a traced int32 scalar) to the
     logits inside the jitted step: FAULT_NAN poisons with NaN, FAULT_INF
@@ -334,6 +346,10 @@ class IterStats:
     queued: int = 0          # queue depth after this iteration's admission
     prefill_slots: int = 0   # slots mid-chunked-prefill this iteration
     decode_slots: int = 0    # slots that ran a decode step this iteration
+    # where the host's time went (perf_counter, always on):
+    host_s: float = 0.0      # wall_s less the time waiting in _fetch
+    gc_s: float = 0.0        # GC pauses since the previous IterStats
+    jit_misses: int = 0      # _calls that grew their program's jit cache
 
 
 class PapiEngine:
@@ -403,10 +419,16 @@ class PapiEngine:
                                         or kv_layout == "paged")
                             if mesh is not None else None))
         self.attn_pim = attn_pim
-        # telemetry: NULL_TRACER's hooks are no-ops and its timed_call is a
-        # bare dispatch, so the traced-off hot path is unchanged (gated by
-        # the traced-vs-untraced A/B in benchmarks/engine_hotpath.py)
+        # telemetry: NULL_TRACER's hooks are no-ops, so the traced-off hot
+        # path is unchanged (gated by the traced-vs-untraced A/B in
+        # benchmarks/engine_hotpath.py); the phase spans reach the
+        # profiler's trace either way
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        # per-step counters behind IterStats.host_s / gc_s / jit_misses
+        self._fetch_s = 0.0        # seconds waiting in _fetch, cumulative
+        self._gc_s = 0.0           # GC pause seconds not yet reported
+        self._gc_span: phase | None = None
+        self._jit_misses = 0       # jit-cache misses, cumulative
         # runtime sanitizer (repro.debug.sanitize): transfer-guard scopes
         # around every step, per-iteration transfer-budget assertions, and
         # a jit-cache compile census — None keeps the hot path untouched
@@ -635,21 +657,23 @@ class PapiEngine:
         crashed = False
         prev = self.stream_chunks
         self.stream_chunks = True
+        gc.callbacks.append(self._on_gc)
         try:
             while True:
                 if stream_open:
-                    try:
-                        got = next(arrivals)
-                    except StopIteration:
-                        stream_open = False
-                    else:
-                        if got is None:
-                            got = []
-                        elif isinstance(got, ServeRequest):
-                            got = [got]
-                        for req in got:
-                            self.submit(req)
-                        self._arrived_this_step = len(got)
+                    with phase("serve.arrivals", self.tracer):
+                        try:
+                            got = next(arrivals)
+                        except StopIteration:
+                            stream_open = False
+                        else:
+                            if got is None:
+                                got = []
+                            elif isinstance(got, ServeRequest):
+                                got = [got]
+                            for req in got:
+                                self.submit(req)
+                            self._arrived_this_step = len(got)
                 if not stream_open and not (self.queue or self.active_slots):
                     completed = True
                     return
@@ -686,6 +710,7 @@ class PapiEngine:
             crashed = True
             raise
         finally:
+            gc.callbacks.remove(self._on_gc)
             self.stream_chunks = prev
             if not completed and not crashed:
                 # the caller broke out of / close()d the generator
@@ -697,6 +722,18 @@ class PapiEngine:
                 # Queued requests stay queued, same contract as run().
                 for s in list(self.active_slots):
                     self._finish_slot(s, "aborted")
+
+    def _on_gc(self, stage: str, info: dict) -> None:
+        """`gc.callbacks` hook that `serve()` installs: each collection is a
+        ``gc`` span (generation as metadata) and its pause is added to the
+        next `IterStats.gc_s`, wherever in the loop it fell."""
+        if stage == "start":
+            self._gc_span = phase("gc", self.tracer,
+                                  generation=info["generation"]).__enter__()
+        elif self._gc_span is not None:
+            span, self._gc_span = self._gc_span, None
+            span.__exit__(None, None, None)
+            self._gc_s += time.perf_counter() - span.start
 
     def _drain_events(self, streamed: dict[int, int], reported: int):
         """Final-event tail for every result appended since `reported`:
@@ -889,15 +926,19 @@ class PapiEngine:
 
     def _fetch(self, *arrays):
         """Single device->host sync round-trip (counted).  Sharded arrays
-        gather here — still one round trip from the host's point of view."""
+        gather here — still one round trip from the host's point of view.
+        Runs as the ``engine.fetch`` span: the host waits on the device, and
+        the wait is kept out of `IterStats.host_s`."""
         self.host_transfers += 1
-        if self._sanitizer is not None:
-            with self._sanitizer.allow_transfers():
+        with phase("engine.fetch", self.tracer) as span:
+            if self._sanitizer is not None:
+                with self._sanitizer.allow_transfers():
+                    # papilint: allow-transfer(the engine's single counted sync point)
+                    got = jax.device_get(arrays)
+            else:
                 # papilint: allow-transfer(the engine's single counted sync point)
                 got = jax.device_get(arrays)
-        else:
-            # papilint: allow-transfer(the engine's single counted sync point)
-            got = jax.device_get(arrays)
+        self._fetch_s += time.perf_counter() - span.start
         return got[0] if len(arrays) == 1 else got
 
     def _scope(self):
@@ -923,17 +964,24 @@ class PapiEngine:
         return (kind, tlp, self.scheduler.fc_assignment, self.pim_interpret,
                 self.attn_pim)
 
-    def _call(self, key: tuple, fn, *args):
-        """Dispatch one compiled program.  Every `_get_*` getter returns its
-        jit-cache key alongside the executable so the dispatch can be timed
-        under THAT key — the per-variant timing table is exactly what a
-        measured-characterization scheduler consumes (ROADMAP).  Under an
-        enabled tracer the wall time is measured around
-        `jax.block_until_ready`; untraced this is the bare call (no block:
-        async dispatch is preserved)."""
-        if self.tracer.enabled:
-            return self.tracer.timed_call(key, fn, *args)
-        return fn(*args)
+    def _call(self, key: tuple, fn, *args,
+              span: str | None = "engine.dispatch"):
+        """Dispatch one compiled program: the bare asynchronous call, never
+        blocked, inside the ``span`` phase (``engine.dispatch`` with the
+        jit-cache key as metadata; None when the caller's own phase covers
+        it).  Every `_get_*` getter returns its key alongside the
+        executable for this label.  A call that grew the function's jit
+        cache (a trace, then a compile or a persistent-cache load) counts
+        in `IterStats.jit_misses`.  Device time per program comes from the
+        profiler trace, under the program's name."""
+        before = fn._cache_size()
+        if span is None:
+            out = fn(*args)
+        else:
+            with phase(span, self.tracer, key=format_program_key(key)):
+                out = fn(*args)
+        self._jit_misses += fn._cache_size() > before
+        return out
 
     def _get_decode(self, which: str):
         """Legacy (unfused) per-call decode step."""
@@ -953,13 +1001,13 @@ class PapiEngine:
         if key not in self._decode_jit:
             cfg = self.cfg
 
-            def plain_step(params, cache, last, fault):
+            def papi_decode_step(params, cache, last, fault):
                 logits, cache = decode_step(cfg, params, cache, last[:, None])
                 logits = _inject_fault(logits, fault)
                 bad = ~jnp.all(jnp.isfinite(logits))
                 return greedy(logits[:, -1]), bad, cache
 
-            self._decode_jit[key] = jax.jit(plain_step)
+            self._decode_jit[key] = jax.jit(papi_decode_step)
         return key, self._decode_jit[key]
 
     def _get_spec_fused(self):
@@ -1075,7 +1123,8 @@ class PapiEngine:
                self.attn_pim)
         if key not in self._prefill_jit:
             fn = prefill_to_pages if self.kv is not None else prefill_to_slots
-            self._prefill_jit[key] = jax.jit(partial(fn, cfg))
+            self._prefill_jit[key] = jax.jit(
+                _named("papi_admit_prefill", partial(fn, cfg)))
         return key, self._prefill_jit[key]
 
     def _get_chunk(self, which: str):
@@ -1087,7 +1136,8 @@ class PapiEngine:
         key = ("chunk_" + which, current_fc_variant(),
                current_fc_interpret(), self.attn_pim)
         if key not in self._prefill_jit:
-            self._prefill_jit[key] = jax.jit(partial(prefill_chunk, cfg))
+            self._prefill_jit[key] = jax.jit(
+                _named("papi_prefill_chunk", partial(prefill_chunk, cfg)))
         return key, self._prefill_jit[key]
 
     # --------------------------------------- continuous batching (serve())
@@ -1103,19 +1153,20 @@ class PapiEngine:
                current_fc_interpret(), self.attn_pim)
         if key not in self._prefill_jit:
             if which == "main":
-                def wave(params, cache, toks, lens, pin_mask, pin_pos,
-                         fault):
+                def papi_mixed_wave(params, cache, toks, lens, pin_mask,
+                                    pin_pos, fault):
                     logits, cache = mixed_step(cfg, params, cache, toks,
                                                lens, pin_mask, pin_pos)
                     logits = _inject_fault(logits, fault)
                     bad = ~jnp.all(jnp.isfinite(logits))
                     return greedy(logits), bad, cache
             else:
-                def wave(params, cache, toks, lens, pin_mask, pin_pos):
+                def papi_mixed_wave(params, cache, toks, lens, pin_mask,
+                                    pin_pos):
                     _, cache = mixed_step(cfg, params, cache, toks, lens,
                                           pin_mask, pin_pos)
                     return cache
-            self._prefill_jit[key] = jax.jit(wave)
+            self._prefill_jit[key] = jax.jit(papi_mixed_wave)
         return key, self._prefill_jit[key]
 
     def _get_oracle_wave(self):
@@ -1134,6 +1185,18 @@ class PapiEngine:
 
             self._prefill_jit[key] = jax.jit(wave)
         return key, self._prefill_jit[key]
+
+    def _get_park(self):
+        """Parks inactive slots' cache positions at 1 (see `_park`): one
+        fixed-shape [max_slots] mask program serves any inactive set."""
+        # papilint: disable=PL003 (a where over positions: no FC or attention to trace)
+        key = ("park",)
+        if key not in self._decode_jit:
+            def papi_park_slots(mask, pos):
+                return jnp.where(mask, jnp.ones((), jnp.int32), pos)
+
+            self._decode_jit[key] = jax.jit(papi_park_slots)
+        return key, self._decode_jit[key]
 
     def _prefilling_slots(self) -> list[int]:
         """Slots mid-chunked-prefill (serve() only: offline admission always
@@ -1240,26 +1303,27 @@ class PapiEngine:
         chunk waves run in ONE fused device program — no prefill stall, one
         dispatch + one fetch per iteration.  Returns the `_decode_all`-shaped
         (out, accepted, finished) bundle for the decoding slots."""
-        ctoks, clens, pin, pin_pos, finals = self._wave_rows(prefilling)
-        chunk_lens = clens.copy()        # prefill rows only, for the draft
-        for s in decoding:
-            ctoks[s, 0] = self.slot_last[s]
-            clens[s] = 1
-        self._ensure_wave_pages(prefilling, chunk_lens)
-        if self.kv is not None:
+        with phase("engine.prepare", self.tracer):
+            ctoks, clens, pin, pin_pos, finals = self._wave_rows(prefilling)
+            chunk_lens = clens.copy()    # prefill rows only, for the draft
             for s in decoding:
-                self.kv.ensure(s, self._slot_pos(s) + 1)
-        self._sync_tables()
-        ct, cl = jnp.asarray(ctoks), jnp.asarray(clens)
-        pm, pp = jnp.asarray(pin), jnp.asarray(pin_pos)
+                ctoks[s, 0] = self.slot_last[s]
+                clens[s] = 1
+            self._ensure_wave_pages(prefilling, chunk_lens)
+            if self.kv is not None:
+                for s in decoding:
+                    self.kv.ensure(s, self._slot_pos(s) + 1)
+            self._sync_tables()
+            ct, cl = jnp.asarray(ctoks), jnp.asarray(clens)
+            pm, pp = jnp.asarray(pin), jnp.asarray(pin_pos)
+            fault = self._fault_code()
         variant = self.scheduler.fc_assignment
         with self._scope(), \
                 fc_variant(variant, interpret=self.pim_interpret), \
                 self._attn_scope():
             wkey, wfn = self._get_wave("main")
             nxt, bad, cache2 = self._call(
-                wkey, wfn, self.params, self.cache, ct, cl, pm, pp,
-                self._fault_code())
+                wkey, wfn, self.params, self.cache, ct, cl, pm, pp, fault)
             if self.draft_cfg is not None and prefilling:
                 # the draft's KV covers the prompt positions (chunk rows
                 # only — the TLP=1 decode path never advances the draft)
@@ -1628,7 +1692,12 @@ class PapiEngine:
             batch_rows.append((slot, req))
         if not batch_rows:
             return 0, False
+        with phase("engine.admit.prefill", self.tracer, rows=len(batch_rows)):
+            return self._prefill_admitted(batch_rows)
 
+    def _prefill_admitted(self, batch_rows) -> tuple[int, bool]:
+        """Prefill the requests `_admit_wave` just placed in slots and
+        commit their first tokens (the ``engine.admit.prefill`` span)."""
         # ---- chunk 0: the compiled fixed-shape prefill (positions 0..P-1)
         tokens = np.zeros((self.max_slots, self.prefill_len), np.int32)
         lens = np.ones(self.max_slots, np.int32)
@@ -1741,21 +1810,35 @@ class PapiEngine:
                 admitted += 1              # counts toward RLP
         return admitted, instant_finish
 
-    def _decode_all(self) -> tuple[np.ndarray, np.ndarray, np.ndarray | None]:
-        """One decoding iteration for all slots.  Returns (new token matrix
-        [slots, <=tlp], accepted counts [slots], eos-finished mask|None)."""
+    def _decode_all(self, decoding: list[int]
+                    ) -> tuple[np.ndarray, np.ndarray, np.ndarray | None]:
+        """One decoding iteration for the `decoding` slots.  Returns (new
+        token matrix [slots, <=tlp], accepted counts [slots], eos-finished
+        mask|None)."""
         variant = self.scheduler.fc_assignment
         tlp = self.spec_len
+        plain = tlp <= 1 or self.draft_cfg is None
+        with phase("engine.prepare", self.tracer):
+            if self.kv is not None:
+                # map pages for the KV this iteration writes (positions
+                # pos..pos+tlp-1).  Cannot fail: the admission reservation
+                # covers prompt + max_new + window, and coverage never
+                # exceeds it before the request finishes.
+                for s in decoding:
+                    self.kv.ensure(s, self._slot_pos(s) + (1 if plain
+                                                           else tlp))
+                self._sync_tables()
+            if plain:
+                last = jnp.asarray(self.slot_last)
+                fault = self._fault_code() if self.fused else None
         with self._scope(), \
                 fc_variant(variant, interpret=self.pim_interpret), \
                 self._attn_scope():
-            if tlp <= 1 or self.draft_cfg is None:
-                last = jnp.asarray(self.slot_last)
+            if plain:
                 if self.fused:
                     fkey, ffn = self._get_plain_fused()
                     nxt, bad, cache2 = self._call(
-                        fkey, ffn, self.params, self.cache, last,
-                        self._fault_code())
+                        fkey, ffn, self.params, self.cache, last, fault)
                     # papilint: allow-transfer(the iteration's one fetch)
                     nxt_h, bad_h = self._fetch(nxt, bad)
                     if bad_h:
@@ -1855,7 +1938,14 @@ class PapiEngine:
         return None if self._sanitizer is None else self._sanitizer.report
 
     def _step_impl(self) -> None:
+        """One engine iteration, as the ``engine.step`` span (step number
+        = the `IterStats.iteration` it records) around its phases:
+        ``engine.admit``, ``engine.prepare``, ``engine.dispatch``,
+        ``engine.fetch``, ``engine.commit``, ``engine.park`` and
+        ``engine.account`` (see `telemetry.phase`)."""
         t0 = time.perf_counter()
+        fetch0 = self._fetch_s
+        misses0 = self._jit_misses
         transfers0 = self.host_transfers
         results0 = len(self.results)
         preempted0 = self.preemptions
@@ -1864,21 +1954,159 @@ class PapiEngine:
             # events emitted anywhere below (including by the page manager,
             # which doesn't know the iteration) default to this step index
             self.tracer.iteration = self.iteration
-        if self.faults is not None and self.faults.crash_now(self.iteration):
-            # simulated process death: no cleanup, no emission, no journal
-            # finalization — exactly what recovery must cope with
-            if self.tracer.enabled:
-                self.tracer.emit("fault", self.iteration, fault="crash")
-            raise EngineCrashError(
-                f"injected crash at iteration {self.iteration}",
-                self.iteration)
-        if self.faults is not None:
-            delay = self.faults.step_delay(self.iteration)
-            if delay > 0:
+        with phase("engine.step", self.tracer, kind="iteration",
+                   step=self.iteration + 1) as step:
+            if self.faults is not None and self.faults.crash_now(
+                    self.iteration):
+                # simulated process death: no cleanup, no emission, no
+                # journal finalization — exactly what recovery must cope
+                # with
                 if self.tracer.enabled:
-                    self.tracer.emit("fault", self.iteration,
-                                     fault="latency", delay_s=delay)
-                time.sleep(delay)
+                    self.tracer.emit("fault", self.iteration, fault="crash")
+                raise EngineCrashError(
+                    f"injected crash at iteration {self.iteration}",
+                    self.iteration)
+            if self.faults is not None:
+                delay = self.faults.step_delay(self.iteration)
+                if delay > 0:
+                    if self.tracer.enabled:
+                        self.tracer.emit("fault", self.iteration,
+                                         fault="latency", delay_s=delay)
+                    time.sleep(delay)
+            with phase("engine.admit", self.tracer) as span:
+                admitted = self._admit_phase()
+                span.set(admitted=admitted)
+            arrived = self._arrived_this_step
+            self._arrived_this_step = 0
+            active = self.active_slots
+            if not active:
+                # Still a step: count it, or `run(max_iterations=)` is a
+                # dead guard — paged admission deferring with nothing
+                # active would spin this loop forever (regression-tested).
+                with phase("engine.account", self.tracer):
+                    self.scheduler.observe_counts(0, admitted)
+                    if self.tracer.enabled:
+                        self._trace_scheduler()
+                    self.iteration += 1
+                    self._watchdog(admitted > 0
+                                   or len(self.results) > results0
+                                   or self.preemptions > preempted0)
+                    self._check_invariants()
+                if self.tracer.enabled:
+                    step.meta.update(
+                        fc_variant=self.scheduler.fc_assignment,
+                        rlp=self.scheduler.rlp, tlp=self.scheduler.tlp,
+                        ai_estimate=self.scheduler.ai_estimate,
+                        new_tokens=0, degraded=0, decode_slots=0,
+                        prefill_slots=0, queued=len(self.queue),
+                        arrivals=arrived,
+                        transfers=self.host_transfers - transfers0,
+                        idle=True)
+                return
+
+            speculating = self.spec_len > 1 and self.draft_cfg is not None
+            prefilling = (self._prefilling_slots() if self.stream_chunks
+                          else [])
+            chunked = len(prefilling)
+            if prefilling and not speculating:
+                # TLP=1 continuous batching: decodes + prefill chunks in ONE
+                # fused program (the wave handles its own page mapping)
+                decoding = [s for s in active if s not in set(prefilling)]
+                out, accepted, _fin = self._mixed_wave_iteration(prefilling,
+                                                                 decoding)
+            else:
+                if prefilling:
+                    # speculative serve: advance the prefill frontier first
+                    # so a slot finishing its prompt this iteration rides the
+                    # verify program below, exactly like offline admission
+                    self._chunk_wave(prefilling)
+                pset = set(prefilling)
+                decoding = [s for s in self.active_slots
+                            if s not in pset
+                            or int(self.slot_offset[s])
+                            >= int(self.slot_prompt[s])]
+                out = np.zeros((self.max_slots, 1), np.int32)
+                accepted = np.zeros(self.max_slots)
+                if decoding:
+                    # the eos flags in the bundle are a device-side
+                    # convenience for callers (launch.serve); the commit
+                    # below re-derives finishes anyway since length-based
+                    # finishes need per-request budgets
+                    out, accepted, _fin = self._decode_all(decoding)
+
+            with phase("engine.commit", self.tracer):
+                iter_tokens, finished_flags = self._commit(
+                    decoding, out, accepted, speculating)
+            with phase("engine.park", self.tracer):
+                self._park()
+            # the PAPI runtime scheduling step (§5.2.2): the per-slot
+            # finished flags go to the scheduler as an array — it sums them
+            # itself; then watchdog, invariants, pool statistics, IterStats
+            with phase("engine.account", self.tracer):
+                self.scheduler.observe_counts(finished_flags, admitted)
+                if self.tracer.enabled:
+                    self._trace_scheduler()
+                self.iteration += 1
+                self._watchdog(admitted > 0 or len(iter_tokens) > 0
+                               or chunked > 0
+                               or len(self.results) > results0
+                               or self.preemptions > preempted0)
+                self._check_invariants()
+                kv_used = kv_free = kv_peak = 0
+                kv_frag = 0.0
+                if self.kv is not None:
+                    live_tokens = sum(self._tokens_written(s)
+                                      for s in range(self.max_slots)
+                                      if self.slot_req[s] is not None)
+                    ps = self.kv.stats(live_tokens)
+                    kv_used, kv_free = ps.mapped, ps.free
+                    kv_peak, kv_frag = ps.watermark, ps.fragmentation
+                wall_s = time.perf_counter() - t0
+                gc_s, self._gc_s = self._gc_s, 0.0
+                self.stats.append(IterStats(
+                    preemptions=self.preemptions - preempted0,
+                    deferral_age=self._defer_age,
+                    degraded=1 if self._degraded_this_step else 0,
+                    iteration=self.iteration,
+                    rlp=self.scheduler.rlp,
+                    tlp=self.scheduler.tlp,
+                    ai_estimate=self.scheduler.ai_estimate,
+                    fc_variant=self.scheduler.fc_assignment,
+                    new_tokens=len(iter_tokens),
+                    accepted=(float(np.mean(accepted[decoding]))
+                              if len(decoding) else 0.0),
+                    wall_s=wall_s,
+                    transfers=self.host_transfers - transfers0,
+                    kv_pages_used=kv_used,
+                    kv_pages_free=kv_free,
+                    kv_page_watermark=kv_peak,
+                    kv_fragmentation=kv_frag,
+                    arrivals=arrived,
+                    admitted=admitted,
+                    queued=len(self.queue),
+                    prefill_slots=chunked,
+                    decode_slots=len(decoding),
+                    host_s=wall_s - (self._fetch_s - fetch0),
+                    gc_s=gc_s,
+                    jit_misses=self._jit_misses - misses0,
+                ))
+                if self.tracer.enabled:
+                    if self.kv is not None:
+                        self.tracer.emit("pool", used=kv_used, free=kv_free,
+                                         watermark=kv_peak,
+                                         fragmentation=kv_frag)
+                    st = self.stats[-1]
+                    step.meta.update(
+                        fc_variant=st.fc_variant, rlp=st.rlp, tlp=st.tlp,
+                        ai_estimate=st.ai_estimate, new_tokens=st.new_tokens,
+                        degraded=st.degraded, decode_slots=st.decode_slots,
+                        prefill_slots=st.prefill_slots, queued=st.queued,
+                        arrivals=st.arrivals, transfers=st.transfers)
+
+    def _admit_phase(self) -> int:
+        """Deadlines, admission, deferral ageing and pool-pressure
+        preemption (the ``engine.admit`` span).  Returns the number of
+        requests admitted."""
         self._expire_deadlines()
         admitted = self._admit()
         # deferral-age accounting: consecutive iterations the SAME queue
@@ -1906,71 +2134,13 @@ class PapiEngine:
             admitted += self._admit()
             if self._deferred_head is None:
                 self._defer_age = 0
-        arrived = self._arrived_this_step
-        self._arrived_this_step = 0
-        active = self.active_slots
-        if not active:
-            # Still a step: count it, or `run(max_iterations=)` is a dead
-            # guard — paged admission deferring with nothing active would
-            # spin this loop forever (regression-tested).
-            self.scheduler.observe_counts(0, admitted)
-            if self.tracer.enabled:
-                self._trace_scheduler()
-            self.iteration += 1
-            self._watchdog(admitted > 0 or len(self.results) > results0
-                           or self.preemptions > preempted0)
-            self._check_invariants()
-            if self.tracer.enabled:
-                self.tracer.span(
-                    "iteration", t0,
-                    fc_variant=self.scheduler.fc_assignment,
-                    rlp=self.scheduler.rlp, tlp=self.scheduler.tlp,
-                    ai_estimate=self.scheduler.ai_estimate, new_tokens=0,
-                    degraded=0, decode_slots=0, prefill_slots=0,
-                    queued=len(self.queue), arrivals=arrived,
-                    transfers=self.host_transfers - transfers0, idle=True)
-            return
+        return admitted
 
-        speculating = self.spec_len > 1 and self.draft_cfg is not None
-        prefilling = self._prefilling_slots() if self.stream_chunks else []
-        chunked = len(prefilling)
-        if prefilling and not speculating:
-            # TLP=1 continuous batching: decodes + prefill chunks in ONE
-            # fused program (the wave handles its own page mapping)
-            decoding = [s for s in active if s not in set(prefilling)]
-            out, accepted, _fin = self._mixed_wave_iteration(prefilling,
-                                                             decoding)
-        else:
-            if prefilling:
-                # speculative serve: advance the prefill frontier first so a
-                # slot finishing its prompt this iteration rides the verify
-                # program below, exactly like offline admission
-                self._chunk_wave(prefilling)
-            pset = set(prefilling)
-            decoding = [s for s in self.active_slots
-                        if s not in pset
-                        or int(self.slot_offset[s])
-                        >= int(self.slot_prompt[s])]
-            out = np.zeros((self.max_slots, 1), np.int32)
-            accepted = np.zeros(self.max_slots)
-            if decoding:
-                if self.kv is not None:
-                    # map pages for the KV this iteration writes (positions
-                    # pos..pos+tlp-1).  Cannot fail: the admission
-                    # reservation covers prompt + max_new + window, and
-                    # coverage never exceeds it before the request finishes.
-                    tlp = self.spec_len if speculating else 1
-                    for s in decoding:
-                        self.kv.ensure(s, self._slot_pos(s) + tlp)
-                    self._sync_tables()
-
-                # the eos flags in the bundle are a device-side convenience
-                # for callers (launch.serve); the host loop below re-derives
-                # finishes anyway since length-based finishes need
-                # per-request budgets
-                out, accepted, _fin = self._decode_all()
-
-        # host-side bookkeeping: append tokens, detect eos / length
+    def _commit(self, decoding: list[int], out, accepted,
+                speculating: bool) -> tuple[list[int], np.ndarray]:
+        """Host-side bookkeeping of the step's tokens (the ``engine.commit``
+        span): append them, detect eos / length, return pages, and journal
+        the commits.  Returns (the tokens committed, finished flags)."""
         iter_tokens: list[int] = []
         finished_flags = np.zeros(self.max_slots, bool)
         for s in decoding:
@@ -2008,75 +2178,21 @@ class PapiEngine:
 
         if self.journal is not None:
             self._journal_commits()
+        return iter_tokens, finished_flags
 
-        # park inactive slots at pos=1 so their garbage decode can't creep
-        # past the cache capacity (they are masked from outputs anyway).
-        # Fixed-shape [max_slots] mask: the same compiled where() serves any
-        # inactive set, unlike a dynamic gather index which retraces per set.
+    def _park(self) -> None:
+        """Park inactive slots at pos=1 so their garbage decode can't creep
+        past the cache capacity (they are masked from outputs anyway), with
+        `papi_park_slots` (the ``engine.park`` span)."""
         inactive = np.array([r is None for r in self.slot_req])
         if inactive.any():
             mask = jnp.asarray(inactive)
-            one = jnp.ones((), jnp.int32)
-            self.cache["pos"] = jnp.where(mask, one, self.cache["pos"])
+            pkey, pfn = self._get_park()
+            self.cache["pos"] = self._call(pkey, pfn, mask, self.cache["pos"],
+                                           span=None)
             if self.draft_cache is not None:
-                self.draft_cache["pos"] = jnp.where(
-                    mask, one, self.draft_cache["pos"])
-
-        # 4) the PAPI runtime scheduling step (§5.2.2): the per-slot finished
-        # flags go to the scheduler as an array — it sums them itself.
-        self.scheduler.observe_counts(finished_flags, admitted)
-        if self.tracer.enabled:
-            self._trace_scheduler()
-        self.iteration += 1
-        self._watchdog(admitted > 0 or len(iter_tokens) > 0 or chunked > 0
-                       or len(self.results) > results0
-                       or self.preemptions > preempted0)
-        self._check_invariants()
-        kv_used = kv_free = kv_peak = 0
-        kv_frag = 0.0
-        if self.kv is not None:
-            live_tokens = sum(self._tokens_written(s)
-                              for s in range(self.max_slots)
-                              if self.slot_req[s] is not None)
-            ps = self.kv.stats(live_tokens)
-            kv_used, kv_free = ps.mapped, ps.free
-            kv_peak, kv_frag = ps.watermark, ps.fragmentation
-        self.stats.append(IterStats(
-            preemptions=self.preemptions - preempted0,
-            deferral_age=self._defer_age,
-            degraded=1 if self._degraded_this_step else 0,
-            iteration=self.iteration,
-            rlp=self.scheduler.rlp,
-            tlp=self.scheduler.tlp,
-            ai_estimate=self.scheduler.ai_estimate,
-            fc_variant=self.scheduler.fc_assignment,
-            new_tokens=len(iter_tokens),
-            accepted=(float(np.mean(accepted[decoding]))
-                      if len(decoding) else 0.0),
-            wall_s=time.perf_counter() - t0,
-            transfers=self.host_transfers - transfers0,
-            kv_pages_used=kv_used,
-            kv_pages_free=kv_free,
-            kv_page_watermark=kv_peak,
-            kv_fragmentation=kv_frag,
-            arrivals=arrived,
-            admitted=admitted,
-            queued=len(self.queue),
-            prefill_slots=chunked,
-            decode_slots=len(decoding),
-        ))
-        if self.tracer.enabled:
-            if self.kv is not None:
-                self.tracer.emit("pool", used=kv_used, free=kv_free,
-                                 watermark=kv_peak, fragmentation=kv_frag)
-            st = self.stats[-1]
-            self.tracer.span(
-                "iteration", t0, fc_variant=st.fc_variant, rlp=st.rlp,
-                tlp=st.tlp, ai_estimate=st.ai_estimate,
-                new_tokens=st.new_tokens, degraded=st.degraded,
-                decode_slots=st.decode_slots,
-                prefill_slots=st.prefill_slots, queued=st.queued,
-                arrivals=st.arrivals, transfers=st.transfers)
+                self.draft_cache["pos"] = self._call(
+                    pkey, pfn, mask, self.draft_cache["pos"], span=None)
 
     def _trace_scheduler(self) -> None:
         """Emit this iteration's scheduling decision with its INPUTS (the

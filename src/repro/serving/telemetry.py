@@ -1,30 +1,32 @@
-"""Engine telemetry: typed event traces, per-program timing, exporters.
+"""Engine telemetry: typed event traces, phase spans, exporters.
 
 PAPI's whole mechanism is *online kernel characterization* — the runtime
 watches per-kernel behavior and schedules compute-bound vs memory-bound
 work accordingly (§5.2).  The engine therefore needs an observation layer
 that is always available and (nearly) free when off:
 
-  * `Tracer` — a bounded ring buffer of typed `Event`s: iteration spans,
-    admission/chunk waves, scheduler decisions (estimate + threshold, not
+  * `Tracer` — a bounded ring buffer of typed `Event`s: iteration and
+    phase spans, scheduler decisions (estimate + threshold, not
     just the verdict), preemptions, deferrals, fault injections, degraded
     re-runs, page-pool occupancy samples, and per-request lifecycle marks
     (submit / admit / first_token / finish).  The buffer keeps the NEWEST
-    events and counts what it dropped; aggregate counters and the
-    per-program timing table live outside the ring, so exports stay exact
-    under truncation.
-  * per-compiled-program timing — `Tracer.timed_call(key, fn, *args)`
-    wraps a jitted dispatch with wall time measured around
-    `jax.block_until_ready`, keyed by the engine's jit-cache key
-    ``(kind, tlp, fc_variant, interpret, attn_pim)``.  The running
-    count/mean/min/max per key is exactly the table a
-    measured-characterization scheduler consumes: it answers "what does
-    the pu-vs-pim variant actually cost at this TLP" from data instead of
-    a statically calibrated alpha.
-  * `NullTracer` — the engine default.  Every hook is a no-op and
-    `timed_call` is a bare dispatch (no block, no timing), so the
-    traced-off hot path is unchanged (gated by the traced-vs-untraced A/B
-    in ``benchmarks/engine_hotpath.py --arrivals --trace``).
+    events and counts what it dropped; aggregate counters live outside
+    the ring, so exports stay exact under truncation.
+  * `phase(name, tracer, **meta)` — one named span of engine work.  It
+    enters `jax.profiler.TraceAnnotation` whenever a profiler session is
+    open, so every engine phase (admission, input preparation, dispatch,
+    fetch, commit, park, accounting, GC pauses) lands in the profiler's
+    trace on the device trace's clock, where a reduction can read the host
+    time between device programs; with no session open it costs one
+    TraceMe check and two clock reads.  Under an enabled `Tracer` the same span is also emitted as a
+    typed ``phase`` event (the engine step as its ``iteration`` event).
+    Device time per compiled program comes from the profiler trace, under
+    the programs' own names (``papi_decode_step``, ``papi_mixed_wave``,
+    ...), never from a host clock around a blocking call.
+  * `NullTracer` — the engine default.  Every hook is a no-op, so the
+    traced-off hot path is the bare dispatch (gated by the
+    traced-vs-untraced A/B in ``benchmarks/engine_hotpath.py --arrivals
+    --trace``).
 
 Exporters (one event vocabulary, three views — see docs/ARCHITECTURE.md,
 "Observability & telemetry"):
@@ -32,17 +34,16 @@ Exporters (one event vocabulary, three views — see docs/ARCHITECTURE.md,
   * `export_chrome` — Chrome-trace-event JSON (`{"traceEvents": [...]}`),
     opens in Perfetto / chrome://tracing.  One lane per engine slot
     (request residency spans + first-token marks), one for the scheduler
-    (iteration spans named by the chosen FC variant, flip instants), one
-    for the page pool (a counter track), one for compiled-program
-    dispatches, one for the queue (submit/defer/fault instants).  The
+    (iteration spans named by the chosen FC variant with their phase
+    spans nested inside, flip instants), one for the page pool (a counter
+    track), one for the queue (submit/defer/fault instants).  The
     full typed-event payload rides in each event's ``args`` and the
     aggregate tables under a top-level ``"papi"`` key, so
     `tools/trace_report.py` reads the same facts from either format.
   * `export_prometheus` — text-exposition snapshot of ``papi_engine_*``
     counters/gauges derived from the same events (iterations, tokens,
     finishes by reason, preemptions, deferrals, degraded steps, faults by
-    kind, scheduler flips, pool occupancy, per-program run counts and
-    total seconds).
+    kind, scheduler flips, pool occupancy).
   * `export_jsonl` — the raw typed events, one JSON object per line, with
     a trailing ``summary`` record carrying the aggregate tables.
 
@@ -53,11 +54,13 @@ the same vocabulary, so one trace format covers every engine mode
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
-import math
 import time
 from collections import Counter, deque
-from typing import Any, Iterable
+from typing import Iterable
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # The event vocabulary.  `tools/trace_report.py` validates traces against
 # this set, so additions here must be mirrored there (it keeps its own
@@ -77,8 +80,8 @@ EVENT_KINDS = frozenset({
     "fault",         # an injected fault fired          {fault, ...}
     "degraded",      # finite-logits guard re-ran the   {mode: step|wave}
                      #   step on the oracle path
-    "program",       # span: one compiled-program       {key, ...}
-                     #   dispatch (traced only)
+    "phase",         # span: one engine phase           {name, ...}
+                     #   (engine.admit, engine.fetch, gc, ...)
     "page_map",      # allocator mapped pages           {slot, pages}
     "page_unmap",    # allocator returned pages         {slot, pages, cause}
     "page_reserve",  # admission reserved a budget      {slot, budget_pages,
@@ -102,31 +105,7 @@ class Event:
     data: dict = dataclasses.field(default_factory=dict)
 
 
-@dataclasses.dataclass
-class ProgramTiming:
-    """Running timing stats for one compiled program (one jit-cache key)."""
-    count: int = 0
-    total_s: float = 0.0
-    min_s: float = math.inf
-    max_s: float = 0.0
-
-    @property
-    def mean_s(self) -> float:
-        return self.total_s / self.count if self.count else 0.0
-
-    def record(self, dur: float) -> None:
-        self.count += 1
-        self.total_s += dur
-        self.min_s = min(self.min_s, dur)
-        self.max_s = max(self.max_s, dur)
-
-    def as_dict(self) -> dict:
-        return {"count": self.count, "total_s": self.total_s,
-                "mean_s": self.mean_s,
-                "min_s": self.min_s if self.count else 0.0,
-                "max_s": self.max_s}
-
-
+@functools.lru_cache(maxsize=None)
 def format_program_key(key: tuple) -> str:
     """Stable string form of a jit-cache key for export/labels, e.g.
     ``('spec_fused', 4, 'pim', None, False)`` -> ``spec_fused|4|pim|-|-``
@@ -136,13 +115,13 @@ def format_program_key(key: tuple) -> str:
 
 
 class Tracer:
-    """Bounded typed-event trace + per-program timing table.
+    """Bounded typed-event trace.
 
     ``capacity`` bounds the event ring (the NEWEST events are kept;
-    ``dropped`` counts the truncated prefix).  Aggregate ``counters``,
-    ``gauges``, and the ``programs`` timing table are maintained at emit
-    time, outside the ring, so the Prometheus snapshot and the report
-    tool's tables stay exact regardless of truncation.
+    ``dropped`` counts the truncated prefix).  Aggregate ``counters`` and
+    ``gauges`` are maintained at emit time, outside the ring, so the
+    Prometheus snapshot and the report tool's tables stay exact regardless
+    of truncation.
 
     ``page_events=True`` opts into the allocator's per-call
     map/unmap/reserve events even without ``debug_invariants`` (they are
@@ -161,7 +140,6 @@ class Tracer:
         self.iteration = 0           # engine refreshes this every step
         self.counters: Counter = Counter()
         self.gauges: dict[str, float] = {}
-        self.programs: dict[tuple, ProgramTiming] = {}
         self._t0 = time.perf_counter()
 
     # ------------------------------------------------------------ emission
@@ -200,45 +178,11 @@ class Tracer:
                     self.gauges[f"kv_pages_{field}"] = data[field]
         return ev
 
-    def span(self, kind: str, start: float, iteration: int | None = None,
-             **data) -> Event:
-        """Emit a span that began at absolute `time.perf_counter()` value
-        ``start`` and ends now."""
-        end = time.perf_counter()
-        return self.emit(kind, iteration, ts=start - self._t0,
-                         dur=end - start, **data)
-
-    # ------------------------------------------------------ program timing
-    def timed_call(self, key: tuple, fn, *args):
-        """Dispatch ``fn(*args)`` and record its wall time (measured around
-        `jax.block_until_ready`) against jit-cache key ``key``.  The block
-        only happens under an enabled tracer — the engine's `_call` hook
-        routes through the bare `fn(*args)` when tracing is off."""
-        import jax   # deferred: exporters/report paths never need jax
-        start = time.perf_counter()
-        # papilint: allow-transfer(timed dispatch must block to measure device wall)
-        out = jax.block_until_ready(fn(*args))
-        self.record_program(key, time.perf_counter() - start, start=start)
-        return out
-
-    def record_program(self, key: tuple, dur: float,
-                       start: float | None = None) -> None:
-        self.programs.setdefault(key, ProgramTiming()).record(dur)
-        ts = None if start is None else start - self._t0
-        self.emit("program", ts=ts, dur=dur, key=format_program_key(key))
-
-    def program_table(self) -> dict[str, dict]:
-        """The per-key timing table, string-keyed for export: the exact
-        shape a measured-characterization scheduler consumes."""
-        return {format_program_key(k): t.as_dict()
-                for k, t in sorted(self.programs.items(), key=lambda kv:
-                                   format_program_key(kv[0]))}
-
 
 class NullTracer:
-    """The engine default: every hook is a no-op, ``timed_call`` is a bare
-    dispatch.  Shares the read surface (events/counters/programs/...) so
-    exporters degrade gracefully on an untraced engine."""
+    """The engine default: every hook is a no-op.  Shares the read surface
+    (events/counters/gauges/...) so exporters degrade gracefully on an
+    untraced engine."""
 
     enabled = False
     page_events = False
@@ -248,25 +192,66 @@ class NullTracer:
     events: tuple = ()
     counters: dict = {}
     gauges: dict = {}
-    programs: dict = {}
 
     def emit(self, kind, iteration=None, *, ts=None, dur=0.0, **data):
         return None
 
-    def span(self, kind, start, iteration=None, **data):
-        return None
-
-    def timed_call(self, key, fn, *args):
-        return fn(*args)
-
-    def record_program(self, key, dur, start=None):
-        return None
-
-    def program_table(self):
-        return {}
-
 
 NULL_TRACER = NullTracer()
+
+
+class phase:
+    """One named span of engine work, written to the profiler's trace and,
+    under an enabled `Tracer`, to its ring.
+
+    While a profiler session is open, entering enters
+    `jax.profiler.TraceAnnotation(name, **meta)`; with ``step`` set,
+    `StepTraceAnnotation(name, step_num=step)` instead, so a reduction of
+    the profiler trace can join the step's spans to its `IterStats` line.
+    With no session open that is one TraceMe check.  When the block exits
+    without an exception and the tracer is enabled, the same span is
+    emitted as the typed event ``kind``: ``phase`` carries
+    ``{name, **meta}``, any other kind (the engine step's ``iteration``)
+    just ``meta``.  ``set(**meta)`` adds
+    metadata known only inside the block, such as an admitted count.
+    ``start`` is the `time.perf_counter()` value at entry.
+    """
+
+    __slots__ = ("name", "tracer", "kind", "step", "meta", "start", "_ann")
+
+    def __init__(self, name: str, tracer=NULL_TRACER, *,
+                 kind: str = "phase", step: int | None = None, **meta):
+        self.name, self.tracer, self.kind = name, tracer, kind
+        self.step, self.meta = step, meta
+        self.start = 0.0
+        self._ann = None
+
+    def __enter__(self) -> "phase":
+        if TraceAnnotation.is_enabled():
+            if self.step is None:
+                self._ann = TraceAnnotation(self.name, **self.meta)
+            else:
+                self._ann = StepTraceAnnotation(
+                    self.name, step_num=self.step, **self.meta)
+            self._ann.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def set(self, **meta) -> None:
+        self.meta.update(meta)
+        if self._ann is not None:
+            self._ann.set_metadata(**meta)
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if exc_type is None and self.tracer.enabled:
+            data = (dict(self.meta, name=self.name) if self.kind == "phase"
+                    else self.meta)
+            self.tracer.emit(self.kind, ts=self.start - self.tracer._t0,
+                             dur=end - self.start, **data)
+        return False
 
 
 # --------------------------------------------------------------- exporters
@@ -283,7 +268,7 @@ def _jsonable(obj):
 
 # Chrome lane (tid) layout inside pid 1 ("papi-engine").  Slot lanes start
 # at SLOT_TID0 so any max_slots fits after the fixed lanes.
-SCHED_TID, POOL_TID, PROG_TID, QUEUE_TID, SLOT_TID0 = 1, 2, 3, 4, 10
+SCHED_TID, POOL_TID, QUEUE_TID, SLOT_TID0 = 1, 2, 4, 10
 _PID = 1
 
 
@@ -293,13 +278,13 @@ def export_chrome(tracer) -> dict:
     Opens directly in Perfetto (ui.perfetto.dev) or chrome://tracing:
     iteration spans on the scheduler lane are named by the FC variant the
     scheduler chose (the pu<->pim flip pattern is visible at a glance,
-    flips marked as instants), each slot lane shows request residency
-    spans with first-token marks, the pool lane is a page-occupancy
-    counter track, and the program lane shows every traced compiled-
-    program dispatch.  The typed payload of every event rides in ``args``
-    (with its ``kind``), and the aggregate counter/gauge/program tables
-    under the top-level ``"papi"`` key — `tools/trace_report.py` consumes
-    those rather than re-deriving from the lanes.
+    flips marked as instants) and hold the step's phase spans, each slot
+    lane shows request residency spans with first-token marks, and the
+    pool lane is a page-occupancy counter track.  The typed payload of
+    every event rides in ``args`` (with its ``kind``), and the aggregate
+    counter/gauge tables under the top-level ``"papi"`` key —
+    `tools/trace_report.py` consumes those rather than re-deriving from
+    the lanes.
     """
     out: list[dict] = []
 
@@ -311,7 +296,6 @@ def export_chrome(tracer) -> dict:
                 "name": "process_name", "args": {"name": "papi-engine"}})
     meta(SCHED_TID, "scheduler")
     meta(POOL_TID, "kv-page-pool")
-    meta(PROG_TID, "programs")
     meta(QUEUE_TID, "queue")
 
     def us(ts: float) -> float:
@@ -353,8 +337,8 @@ def export_chrome(tracer) -> dict:
             rec["args"] = {"used": ev.data.get("used", 0),
                            "free": ev.data.get("free", 0)}
             out.append(rec)
-        elif ev.kind == "program":
-            rec = base(ev, PROG_TID, "X", ev.data.get("key", "program"))
+        elif ev.kind == "phase":
+            rec = base(ev, SCHED_TID, "X", ev.data.get("name", "phase"))
             rec["dur"] = us(ev.dur)
             out.append(rec)
         elif ev.kind == "admit":
@@ -397,7 +381,6 @@ def export_chrome(tracer) -> dict:
         "papi": {
             "counters": dict(tracer.counters),
             "gauges": dict(tracer.gauges),
-            "programs": tracer.program_table(),
             "events_emitted": tracer.emitted,
             "events_dropped": tracer.dropped,
         },
@@ -419,7 +402,6 @@ def export_jsonl(tracer) -> str:
          "ts": 0.0, "dur": 0.0,
          "data": {"counters": dict(tracer.counters),
                   "gauges": dict(tracer.gauges),
-                  "programs": tracer.program_table(),
                   "events_emitted": tracer.emitted,
                   "events_dropped": tracer.dropped}},
         default=_jsonable, sort_keys=True))
@@ -434,7 +416,7 @@ def export_prometheus(tracer) -> str:
     """Prometheus text-exposition snapshot of ``papi_engine_*`` series,
     derived from the tracer's aggregate counters/gauges (NOT the ring, so
     truncation never undercounts).  Counter series end in ``_total``;
-    pool occupancy and per-program means are gauges."""
+    pool occupancy is a gauge."""
     c, g = tracer.counters, tracer.gauges
     lines: list[str] = []
 
@@ -490,20 +472,6 @@ def export_prometheus(tracer) -> str:
     metric("papi_engine_kv_fragmentation", "gauge",
            "Tail-of-page waste share of mapped rows (latest sample).",
            [("", g.get("kv_pages_fragmentation", 0.0))])
-    table = tracer.program_table()
-    metric("papi_engine_program_runs_total", "counter",
-           "Compiled-program dispatches, by jit-cache key.",
-           [(f'{{key="{_prom_escape(k)}"}}', t["count"])
-            for k, t in table.items()] or [("", 0)])
-    metric("papi_engine_program_seconds_total", "counter",
-           "Wall seconds inside compiled programs (around "
-           "block_until_ready), by jit-cache key.",
-           [(f'{{key="{_prom_escape(k)}"}}', t["total_s"])
-            for k, t in table.items()] or [("", 0.0)])
-    metric("papi_engine_program_mean_seconds", "gauge",
-           "Mean wall seconds per dispatch, by jit-cache key.",
-           [(f'{{key="{_prom_escape(k)}"}}', t["mean_s"])
-            for k, t in table.items()] or [("", 0.0)])
     metric("papi_engine_trace_events_total", "counter",
            "Typed trace events emitted.", [("", tracer.emitted)])
     metric("papi_engine_trace_events_dropped_total", "counter",

@@ -8,11 +8,15 @@
   * the chrome export survives a json round trip with valid ph/ts/pid and
     carries the aggregate tables under "papi"; the Prometheus snapshot is
     line-parseable text exposition;
-  * the per-program timing table is hand-countable on a single greedy
-    request (1 prefill dispatch + max_new-1 decode dispatches);
+  * the dispatch spans are hand-countable on a single greedy request (1
+    prefill dispatch + max_new-1 decode dispatches), and no dispatch
+    blocks under an enabled tracer;
   * tracing is observation only: serve() token streams are BIT-IDENTICAL
     traced vs untraced, and the NullTracer default keeps every hook a
     no-op;
+  * IterStats says where the host's time went: host_s <= wall_s, GC
+    pauses in gc_s (and as `gc` spans), jit-cache misses on each
+    program's first step and none at steady state;
   * scheduler events carry the AI estimate AND the alpha threshold, flips
     match `num_reschedules`; degraded/fault events match the engine's own
     counts on a spec+paged+faults run; a watchdog stall lands a final
@@ -21,6 +25,7 @@
   * `latency_summary` reports per-metric sample counts and tpot_s only
     over requests with >= 2 tokens.
 """
+import gc
 import json
 import re
 import sys
@@ -35,7 +40,7 @@ from repro.serving import (EngineStallError, FaultInjector, PapiEngine,
                            ServeRequest, Tracer, export_chrome, export_jsonl,
                            export_prometheus, latency_summary, write_trace)
 from repro.serving.telemetry import (EVENT_KINDS, NULL_TRACER,
-                                     format_program_key)
+                                     format_program_key, phase)
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "tools"))
 import trace_report  # noqa: E402  (tools/ is not a package)
@@ -86,14 +91,14 @@ def test_ring_truncation_keeps_newest_and_counts_dropped():
 
 
 def test_null_tracer_is_inert():
-    calls = []
     assert NULL_TRACER.emit("finish", req_id=0) is None
-    assert NULL_TRACER.span("iteration", 0.0) is None
-    out = NULL_TRACER.timed_call(("k",), lambda x: calls.append(x) or x, 7)
-    assert out == 7 and calls == [7]      # bare dispatch, no block/record
-    assert NULL_TRACER.program_table() == {}
+    with phase("engine.fetch", NULL_TRACER) as span:
+        span.set(rows=3)                  # metadata, recorded nowhere
+    assert span.meta == {"rows": 3} and span.start > 0
+    assert not hasattr(NULL_TRACER, "timed_call")   # no blocking dispatch
     assert not NULL_TRACER.enabled
     assert list(NULL_TRACER.events) == []
+    assert NULL_TRACER.counters == {}
 
 
 # ------------------------------------------------- traced engine: vocabulary
@@ -152,7 +157,7 @@ def test_all_exporters_cover_every_event_kind(tmp_path):
         "pool": dict(used=1, free=7, watermark=2, fragmentation=0.0),
         "fault": dict(fault="logits_nan"),
         "degraded": dict(mode="step"),
-        "program": dict(key="decode|spec_len=1"),
+        "phase": dict(name="engine.dispatch", key="plain_fused|1|pim|-|-"),
         "page_map": dict(slot=0, pages=2),
         "page_unmap": dict(slot=0, pages=2, cause="finish"),
         "page_reserve": dict(slot=0, budget_pages=4, mapped_pages=2),
@@ -214,7 +219,13 @@ def test_chrome_export_round_trip(small_model, tmp_path):
     papi = doc["papi"]
     assert papi["counters"]["iteration"] == eng.iteration
     assert papi["events_dropped"] == 0
-    assert papi["programs"], "traced run must record program timings"
+    assert "programs" not in papi
+    # the phase spans sit on the scheduler lane, inside the iteration spans
+    phases = [r for r in doc["traceEvents"]
+              if r["ph"] == "X" and r["args"].get("kind") == "phase"]
+    assert phases and all(r["tid"] == 1 for r in phases)
+    assert {r["name"] for r in phases} >= {"engine.admit", "engine.fetch",
+                                           "engine.dispatch"}
     # every admitted request got a residency span on a slot lane
     slot_spans = [r for r in doc["traceEvents"]
                   if r["ph"] == "X" and r.get("name", "").startswith("req ")]
@@ -241,8 +252,11 @@ def test_prometheus_export_parses(small_model):
                      "papi_engine_preemptions_total",
                      "papi_engine_degraded_steps_total",
                      "papi_engine_kv_pages_used",
-                     "papi_engine_program_runs_total"):
+                     "papi_engine_events_total"):
         assert required in names
+    # no host-clock program timing: the profiler trace has device time
+    assert not any(n.startswith("papi_engine_program_") for n in names)
+    assert 'papi_engine_events_total{kind="phase"} 0' not in text
     # values come from the aggregates, not the ring
     assert (f"papi_engine_iterations_total {eng.iteration}"
             in text.splitlines())
@@ -259,42 +273,150 @@ def test_jsonl_export_has_trailing_summary(small_model):
     assert all(r["kind"] in EVENT_KINDS for r in recs[:-1])
     assert recs[-1]["kind"] == "summary"
     assert recs[-1]["data"]["counters"]["iteration"] == eng.iteration
-    assert recs[-1]["data"]["programs"]
+    assert "programs" not in recs[-1]["data"]
+    names = {r["data"]["name"] for r in recs if r["kind"] == "phase"}
+    assert {"engine.admit", "engine.prepare", "engine.dispatch",
+            "engine.fetch", "engine.commit", "engine.park",
+            "engine.account"} <= names
 
 
-# ----------------------------------------------------------- program timing
+# ------------------------------------------------------------ phase spans
 
-def test_program_table_hand_counted(small_model):
+def test_dispatch_spans_hand_counted(small_model):
     """One greedy request, max_new=5, eos never fires: exactly 1 main
     prefill dispatch and 4 plain_fused decode dispatches (prefill commits
-    token 1; each later iteration commits one)."""
+    token 1; each later iteration commits one), each an engine.dispatch
+    span with its jit key, and one fetch per dispatch."""
     cfg, params = small_model
     tr = Tracer()
     eng = _engine(cfg, params, tracer=tr)
     eng.submit(ServeRequest(0, [3, 5, 7], max_new_tokens=5))
     res = eng.run(max_iterations=30)
     assert len(res[0].tokens) == 5
-    table = tr.program_table()
+    spans = [ev for ev in tr.events if ev.kind == "phase"]
     by_kind = {}
-    for key, t in table.items():
-        by_kind[key.split("|")[0]] = by_kind.get(key.split("|")[0], 0) \
-            + t["count"]
-    assert by_kind.get("main") == 1
-    assert by_kind.get("plain_fused") == 4
-    for t in table.values():
-        assert t["count"] >= 1
-        assert 0.0 <= t["min_s"] <= t["mean_s"] <= t["max_s"]
-        assert abs(t["mean_s"] * t["count"] - t["total_s"]) < 1e-9
-    # program events carry the formatted key and a nonzero duration
-    progs = [ev for ev in tr.events if ev.kind == "program"]
-    assert len(progs) == sum(t["count"] for t in table.values())
-    assert all(ev.dur > 0 for ev in progs)
+    for ev in spans:
+        if ev.data["name"] == "engine.dispatch":
+            kind = ev.data["key"].split("|")[0]
+            by_kind[kind] = by_kind.get(kind, 0) + 1
+    assert by_kind == {"main": 1, "plain_fused": 4}
+    fetches = [ev for ev in spans if ev.data["name"] == "engine.fetch"]
+    assert len(fetches) == 5
+    assert all(ev.dur >= 0 for ev in spans)
+
+
+def test_named_programs_lower_to_the_same_hlo(small_model):
+    """A program's stable name (`jit_papi_admit_prefill` in the profiler's
+    trace, where a bare partial reads `jit__unknown`) changes nothing but
+    the module's name."""
+    from functools import partial
+
+    import jax.numpy as jnp
+
+    from repro.models import init_paged_cache, prefill_to_pages
+    from repro.serving.engine import _named
+
+    cfg, params = small_model
+    cache = init_paged_cache(cfg, 4, 17, 8, 8)
+    args = (params, {"tokens": jnp.zeros((4, 8), jnp.int32),
+                     "prompt_lens": jnp.ones(4, jnp.int32)},
+            cache, jnp.zeros(4, jnp.int32))
+    fn = partial(prefill_to_pages, cfg)
+    bare = jax.jit(fn).lower(*args).as_text()
+    named = jax.jit(_named("papi_admit_prefill", fn)).lower(*args).as_text()
+    assert "@jit_papi_admit_prefill" in named
+
+    def strip(text):
+        return re.sub(r"@jit_\w+", "@jit_", text)
+
+    assert strip(named) == strip(bare)
 
 
 def test_format_program_key_compresses_defaults():
     assert format_program_key(("spec_fused", 4, "pim", None, False)) == \
         "spec_fused|4|pim|-|-"
     assert format_program_key(("main", "pu", True, True)) == "main|pu|True|True"
+
+
+def test_traced_dispatch_never_blocks(small_model, monkeypatch):
+    """An enabled Tracer observes dispatch without serialising it: no
+    jax.block_until_ready anywhere on the engine's path."""
+    cfg, params = small_model
+    blocks = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: blocks.append(1) or real(x))
+    tr = Tracer()
+    eng = _engine(cfg, params, tracer=tr)
+    for ev in eng.serve([[ServeRequest(0, [3, 5, 7], max_new_tokens=4)],
+                         [ServeRequest(1, list(range(3, 20)), 3)]]):
+        pass
+    assert tr.counters["phase"] > 0
+    assert blocks == []
+
+
+# ---------------------------------------------- where the host time went
+
+def _serve_all(eng, schedule):
+    return {ev.req_id: ev.result.tokens
+            for ev in eng.serve([list(w) for w in schedule]) if ev.finished}
+
+
+STREAM = [[ServeRequest(0, [3, 5, 7], max_new_tokens=6)], [],
+          [ServeRequest(1, list(range(3, 23)), max_new_tokens=5)], [],
+          [ServeRequest(2, [5, 7, 9, 11], max_new_tokens=4)]]
+
+
+def test_host_s_within_wall_s(small_model):
+    cfg, params = small_model
+    eng = _engine(cfg, params)
+    _serve_all(eng, STREAM)
+    assert eng.stats
+    for st in eng.stats:
+        assert 0.0 <= st.host_s <= st.wall_s
+
+
+def test_gc_pause_counts_in_gc_s_and_as_a_gc_span(small_model, monkeypatch):
+    """A full collection forced inside every step's accounting: its pause
+    lands in that step's IterStats.gc_s and in a `gc` span, and the hook
+    is gone once serve() returns."""
+    cfg, params = small_model
+    tr = Tracer()
+    eng = _engine(cfg, params, tracer=tr)
+    check = eng._check_invariants
+
+    def collect_then_check():
+        gc.collect()
+        check()
+
+    monkeypatch.setattr(eng, "_check_invariants", collect_then_check)
+    _serve_all(eng, STREAM)
+    assert eng._on_gc not in gc.callbacks
+    assert all(st.gc_s > 0 for st in eng.stats)
+    spans = [ev for ev in tr.events
+             if ev.kind == "phase" and ev.data["name"] == "gc"]
+    assert any(ev.data["generation"] == 2 for ev in spans)
+    assert sum(ev.dur for ev in spans) == pytest.approx(
+        sum(st.gc_s for st in eng.stats), rel=0.05, abs=1e-4)
+
+
+def test_jit_misses_on_first_use_only(small_model):
+    """Each program's first dispatch grows its jit cache (a trace and a
+    compile), every later one hits it: the misses add up to the programs
+    the engine compiled, and a second identical stream misses nothing."""
+    cfg, params = small_model
+    eng = _engine(cfg, params)
+    _serve_all(eng, STREAM)
+    compiled = [*eng._decode_jit.values(), *eng._prefill_jit.values()]
+    assert sum(st.jit_misses for st in eng.stats) == sum(
+        fn._cache_size() for fn in compiled)
+    assert eng.stats[0].jit_misses > 0
+    first = len(eng.stats)
+    _serve_all(eng, [[ServeRequest(10 + r.req_id, r.prompt,
+                                   r.max_new_tokens) for r in w]
+                     for w in STREAM])
+    assert [st.jit_misses for st in eng.stats[first:]] == \
+        [0] * (len(eng.stats) - first)
 
 
 # ------------------------------------------------- observation only (serve)
@@ -342,7 +464,9 @@ def test_scheduler_events_carry_estimate_and_threshold(small_model,
     assert len(flips) <= eng.scheduler.num_reschedules
     # a spec run exercises >= 2 distinct compiled programs (draft + verify
     # at minimum; pu/pim variants when the scheduler flips)
-    assert len(tr.program_table()) >= 2
+    keys = {ev.data["key"] for ev in tr.events
+            if ev.kind == "phase" and ev.data["name"] == "engine.dispatch"}
+    assert len(keys) >= 2
 
 
 def test_faults_and_degraded_events_match_engine_counts(small_model,
@@ -429,7 +553,10 @@ def test_trace_report_validates_both_formats(small_model, tmp_path):
     _, summ_c = trace_report.load_trace(chrome)
     _, summ_j = trace_report.load_trace(jsonl)
     assert summ_c["counters"] == summ_j["counters"]
-    assert summ_c["programs"].keys() == summ_j["programs"].keys()
+    ev_c, _ = trace_report.load_trace(chrome)
+    ev_j, _ = trace_report.load_trace(jsonl)
+    assert ([e["data"]["name"] for e in ev_c if e["kind"] == "phase"]
+            == [e["data"]["name"] for e in ev_j if e["kind"] == "phase"])
 
 
 def test_trace_report_rejects_bad_traces(tmp_path):

@@ -234,8 +234,8 @@ def main() -> int:
             failures.append(
                 f"tracing overhead regressed: traced median wall "
                 f"{overhead:+.1%} over untraced > ceiling "
-                f"{TELEMETRY_OVERHEAD_CEIL:.0%} (timed_call grew a real "
-                "per-dispatch cost?)")
+                f"{TELEMETRY_OVERHEAD_CEIL:.0%} (a tracer hook grew a "
+                "real per-step cost?)")
         if dropped:
             failures.append(
                 f"telemetry ring dropped {dropped} events on the bench "

@@ -9,9 +9,10 @@ key; the typed payload rides in each event's ``args`` and the aggregate
 tables under the top-level ``"papi"`` key) or raw JSONL (one typed event
 per line plus a trailing ``summary`` record) — and prints:
 
-  * the per-compiled-program timing table by jit-cache key (count / mean /
-    min / max / total wall seconds around `block_until_ready`) — the table
-    a measured-characterization scheduler consumes;
+  * host seconds by engine phase (`engine.admit`, `engine.fetch`, `gc`,
+    ...: the typed ``phase`` spans, summed by name) — device time per
+    compiled program lives in the profiler's trace instead
+    (`python3 bench/trace_reduce.py <dir>`);
   * the scheduler flip timeline: every pu<->pim reschedule with the AI
     estimate and the alpha threshold that flipped it;
   * page-pool occupancy: high-water mark and the peak sampled used/free;
@@ -39,11 +40,9 @@ from pathlib import Path
 # mirror of repro.serving.telemetry.EVENT_KINDS (tests assert equality)
 EVENT_KINDS = frozenset({
     "submit", "admit", "first_token", "finish", "preempt", "defer",
-    "scheduler", "iteration", "pool", "fault", "degraded", "program",
+    "scheduler", "iteration", "pool", "fault", "degraded", "phase",
     "page_map", "page_unmap", "page_reserve", "stall", "journal", "recover",
 })
-
-PROGRAM_FIELDS = ("count", "total_s", "mean_s", "min_s", "max_s")
 
 
 def load_trace(path: Path) -> tuple[list[dict], dict]:
@@ -51,7 +50,7 @@ def load_trace(path: Path) -> tuple[list[dict], dict]:
 
     Events are normalized to ``{"kind", "iteration", "ts", "dur", "data"}``
     with ts/dur in seconds; the summary dict carries ``counters``,
-    ``gauges``, ``programs``, ``events_emitted``, ``events_dropped``.
+    ``gauges``, ``events_emitted``, ``events_dropped``.
     """
     text = path.read_text()
     head = text.lstrip()[:1]
@@ -106,14 +105,9 @@ def validate(events: list[dict], summary: dict) -> list[str]:
             problems.append("... (truncated)")
             break
     counters = summary.get("counters", {})
-    programs = summary.get("programs", {})
-    if not isinstance(counters, dict) or not isinstance(programs, dict):
-        problems.append("summary counters/programs tables missing")
+    if not isinstance(counters, dict):
+        problems.append("summary counters table missing")
         return problems
-    for key, table in programs.items():
-        missing = [f for f in PROGRAM_FIELDS if f not in table]
-        if missing:
-            problems.append(f"program {key!r}: missing fields {missing}")
     # liveness: a trace of a real run must contain scheduler decisions and
     # iteration spans — zero of either means the engine hooks regressed.
     # Counts come from the aggregate counters (exact under ring truncation;
@@ -138,7 +132,6 @@ def report(events: list[dict], summary: dict, out=None) -> None:
     w = out.write
     counters = summary.get("counters", {})
     gauges = summary.get("gauges", {})
-    programs = summary.get("programs", {})
 
     w(f"events: {summary.get('events_emitted', len(events))} emitted, "
       f"{summary.get('events_dropped', 0)} dropped from the ring "
@@ -149,18 +142,18 @@ def report(events: list[dict], summary: dict, out=None) -> None:
       f"preemptions: {counters.get('preempt', 0)}   "
       f"deferrals: {counters.get('defer', 0)}\n")
 
-    # ---- per-variant program timing (the jit-cache-key table) ----
-    if programs:
-        w("\nprogram timing by jit-cache key "
-          "(kind|tlp|fc_variant|interpret|attn_pim):\n")
-        w(f"  {'key':42s} {'runs':>5s} {'mean':>10s} {'min':>10s} "
-          f"{'max':>10s} {'total':>10s}\n")
-        rows = sorted(programs.items(),
-                      key=lambda kv: -kv[1].get("total_s", 0))
-        for key, t in rows:
-            w(f"  {key:42s} {t['count']:5d} {_fmt_s(t['mean_s'])} "
-              f"{_fmt_s(t['min_s'])} {_fmt_s(t['max_s'])} "
-              f"{_fmt_s(t['total_s'])}\n")
+    # ---- host time by engine phase (typed phase spans in the file) ----
+    phases: dict[str, list[float]] = {}
+    for ev in events:
+        if ev["kind"] == "phase":
+            phases.setdefault(ev["data"].get("name", "?"), []).append(
+                ev["dur"])
+    if phases:
+        w("\nhost time by engine phase (spans in the file):\n")
+        w(f"  {'phase':22s} {'spans':>6s} {'mean':>10s} {'total':>10s}\n")
+        for name, durs in sorted(phases.items(), key=lambda kv: -sum(kv[1])):
+            w(f"  {name:22s} {len(durs):6d} "
+              f"{_fmt_s(sum(durs) / len(durs))} {_fmt_s(sum(durs))}\n")
 
     # ---- scheduler flip timeline ----
     flips = [ev for ev in events
@@ -256,7 +249,7 @@ def main(argv=None) -> int:
         print(f"trace_report: {path} valid — {len(events)} events, "
               f"{counters.get('scheduler', 0)} scheduler decisions, "
               f"{counters.get('iteration', 0)} iteration spans, "
-              f"{len(summary.get('programs', {}))} program keys")
+              f"{counters.get('phase', 0)} phase spans")
         return 0
     report(events, summary)
     return 0
