@@ -40,16 +40,22 @@ available *before* each grid step's DMA is issued.
 Ragged block skipping
 ---------------------
 A continuous batch is ragged: slot A may hold 2000 cached tokens while slot
-B holds 40, yet the grid runs `capacity // block_k` KV steps for both.  With
-``block_skip=True`` (default) two things happen for blocks entirely past a
-request's cache length:
+B holds 40.  This dense kernel's grid still runs `capacity // block_k` KV
+steps for every slot; with ``block_skip=True`` (default) a step whose block
+lies entirely past the request's cache length does no work:
 
   * the K/V `index_map` clamps the block index to the request's last valid
     block — consecutive grid steps then fetch the *same* block, which the
     Pallas pipeline recognizes and elides the redundant HBM->VMEM DMA;
-  * the kernel body wraps the whole score/softmax/accumulate computation in
-    a `pl.when(kb * block_k < length)` no-op, so fully-masked tiles spend
-    neither MXU nor VPU cycles.
+  * the kernel body wraps the score/softmax/accumulate computation
+    (`block_update`) in a `pl.when(kb * block_k < length)` no-op, so
+    fully-masked tiles spend neither MXU nor VPU cycles.
+
+The dead grid steps themselves still run (their index map, the pipeline's
+bookkeeping, the empty body).  The paged kernel
+(`kernels/paged_decode_attention.py`), which serves the engine, runs no
+step for a dead block at all: its grid is each slot's live compute blocks,
+and it copies only the live pages.  Both call the same `block_update`.
 
 Numerics are bit-identical with skipping on or off for any `lens >= 1`
 batch (tested): a fully-masked tile contributes p = exp(NEG_INF - m) = +0.0
@@ -70,6 +76,79 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
+BLOCK_K = 256      # default KV positions per block
+
+
+def init_state(acc_ref, m_ref, l_ref):
+    """Empty online-softmax state, before a request's first KV block."""
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def block_update(q_ref, k_refs, v_refs, acc_ref, m_ref, l_ref, *, start,
+                 length, q_rows=1):
+    """Fold one KV block into the online-softmax state: the per-block math
+    of both flash-decode kernels (the paged kernel calls it too, so the
+    two are bit-identical at equal block sizes).
+
+    q_ref [nkv, R, hd]; k_refs, v_refs: refs [n, nkv, hd] whose rows,
+    in order, hold the block_k KV positions ``start .. start + block_k -
+    1`` (one ref for the dense kernel, one per page for the paged one);
+    acc/m/l as in `_kernel`.  Positions at or past a row's limit are masked
+    to NEG_INF, so their K may hold anything; their V must be finite
+    (p = 0 multiplies it).  Each head's sums run over its own block_k
+    positions only, so the result does not depend on how many KV heads
+    share the block (a KV-head shard gives the same bits)."""
+    def _head(h):
+        q = q_ref[h]                                      # [R, hd]
+        k = jnp.concatenate([r[:, h, :] for r in k_refs])  # [block_k, hd]
+        v = jnp.concatenate([r[:, h, :] for r in v_refs])  # [block_k, hd]
+        scale = 1.0 / math.sqrt(q.shape[-1])
+
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                         # [R, block_k]
+
+        kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        if q_rows == 1:
+            # plain ragged mask — the seed kernel's exact expression
+            limit = length
+        else:
+            # intra-window causal mask: window row r (= row-index // g) sits
+            # at absolute position length - q_rows + r, so it sees KV
+            # positions j < length - (q_rows - 1) + r
+            g = s.shape[0] // q_rows
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
+            limit = length - (q_rows - 1) + row
+        s = jnp.where(kv_pos < limit, s, NEG_INF)
+
+        m_prev = m_ref[h, :, 0:1]                         # [R, 1]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)        # [R, 1]
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)                            # [R, block_k]
+        alpha = jnp.exp(m_prev - m_new)                   # [R, 1]
+
+        l_new = l_ref[h, :, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                 # [R, hd]
+        acc_ref[h] = acc_ref[h] * alpha + pv
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    # the KV heads share one block (see `decode_attention`); each runs its
+    # own online softmax over its R query rows
+    for h in range(q_ref.shape[0]):
+        _head(h)
+
+
+def finalize(o_ref, acc_ref, l_ref):
+    """Normalize the accumulated state into the output block."""
+    l = l_ref[:, :, 0:1]
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _kernel(
@@ -91,68 +170,17 @@ def _kernel(
     kb = pl.program_id(1)
     length = lens_ref[i]
 
-    @pl.when(kb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    def _head(h):
-        q = q_ref[h]                                      # [g, hd]
-        k = k_ref[:, h, :]                                # [block_k, hd]
-        v = v_ref[:, h, :]                                # [block_k, hd]
-        scale = 1.0 / math.sqrt(q.shape[-1])
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                         # [g, block_k]
-
-        kv_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if q_rows == 1:
-            # plain ragged mask — the seed kernel's exact expression
-            limit = length
-        else:
-            # intra-window causal mask: window row r (= row-index // g) sits
-            # at absolute position length - q_rows + r, so it sees KV
-            # positions j < length - (q_rows - 1) + r
-            g = s.shape[0] // q_rows
-            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
-            limit = length - (q_rows - 1) + row
-        s = jnp.where(kv_pos < limit, s, NEG_INF)
-
-        m_prev = m_ref[h, :, 0:1]                         # [g, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)        # [g, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                            # [g, block_k]
-        alpha = jnp.exp(m_prev - m_new)                   # [g, 1]
-
-        l_new = l_ref[h, :, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                 # [g, hd]
-        acc_ref[h] = acc_ref[h] * alpha + pv
-        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-        l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
-
-    def _compute():
-        # the KV heads share one block (see `decode_attention`); each runs
-        # its own online softmax over its g query rows
-        for h in range(q_ref.shape[0]):
-            _head(h)
-
+    pl.when(kb == 0)(lambda: init_state(acc_ref, m_ref, l_ref))
+    compute = functools.partial(
+        block_update, q_ref, [k_ref], [v_ref], acc_ref, m_ref, l_ref,
+        start=kb * block_k, length=length, q_rows=q_rows)
     if block_skip:
         # fully-masked tile => no-op (the DMA was already elided by the
         # clamped index_map; this skips the compute as well)
-        pl.when(kb * block_k < length)(_compute)
+        pl.when(kb * block_k < length)(compute)
     else:
-        _compute()
-
-    @pl.when(kb == num_kb - 1)
-    def _finalize():
-        l = l_ref[:, :, 0:1]
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        compute()
+    pl.when(kb == num_kb - 1)(lambda: finalize(o_ref, acc_ref, l_ref))
 
 
 @functools.partial(
@@ -163,7 +191,7 @@ def decode_attention(
     v_cache: jax.Array,    # [b, S, nkv, hd]
     lens: jax.Array,       # [b] int32 valid lengths (ALL q_rows included)
     *,
-    block_k: int = 256,
+    block_k: int = BLOCK_K,
     interpret: bool | None = None,
     block_skip: bool = True,
     q_rows: int = 1,
@@ -233,7 +261,7 @@ def decode_attention_sharded(
     *,
     mesh,
     axis: str = "model",
-    block_k: int = 256,
+    block_k: int = BLOCK_K,
     interpret: bool | None = None,
     block_skip: bool = True,
     q_rows: int = 1,

@@ -5,28 +5,45 @@ contiguous KV slab.  Under the paged KV cache the physical layout is a pool
 of fixed-size pages (`[num_pages, page_size, nkv, hd]` — one page per
 Attn-PIM bank row, see `serving/kv_pages.py`) and a per-request block table
 maps logical KV blocks to physical pages.  This kernel runs the SAME online
-softmax over that layout:
+softmax over that layout, and reads only each request's live pages:
 
-  grid = (batch, max_blocks)              last axis innermost/sequential
-  scalar prefetch:  lens   [b]            per-request valid lengths
+  grid = (steps,)          each slot's live compute blocks, slot by slot;
+                           `steps` is computed from `lens` at run time
+  scalar prefetch:  slot, block [b * cdiv(max_blocks, ppc)]  per step
+                    lens   [b]             per-request valid lengths
                     tables [b, max_blocks] logical block -> physical page
+  K/V operands:     the pool, ppc times each: operand j is page j of the
+                    step's compute block, [page_size, nkv, hd]
 
-The K/V `index_map` resolves `tables[i, kb]` *before* each grid step's DMA
-is issued (that is what `PrefetchScalarGridSpec` buys us), so the gather is
-free: the pipeline simply fetches block `kb`'s page from wherever it
-physically lives.  No `[b, S, ...]` contiguous view is ever materialized.
+Live-page walk
+--------------
+A compute block holds ``ppc`` pages, ``bk = ppc * page_size`` KV positions.
+Slot i gets exactly ``cdiv(lens[i], bk)`` grid steps (one for an empty
+slot, whose output stays zero), and step (i, n) brings in the pages
+``n * ppc .. n * ppc + ppc - 1`` through ``ppc`` K and ``ppc`` V operands,
+each with an index map that reads ``tables[i, kb]`` from the prefetched
+table.  The pipeline double-buffers every operand, so the next step's
+pages (the next block, or the next slot's first) are copied while the
+current block computes.  No grid step runs and no page is copied for a
+dead block, so the kernel's work follows the live context, not
+``b * max_blocks``.  Operands past a slot's last live page (the tail of its
+last block) repeat that page, which the position mask hides.
 
-Ragged block skipping carries over unchanged: for blocks entirely past a
-request's length, the logical block index is clamped to the last valid one
-(consecutive grid steps then fetch the same physical page, and the Pallas
-pipeline elides the redundant DMA) and the kernel body no-ops via
-`pl.when`.
+Why operands and not hand-written copies: the pool's minor dim (hd = 64 at
+qwen2-0.5b) is under the 128-lane tile, and the installed Mosaic refuses a
+DMA from any slice of such an HBM ref ("Slice shape along dimension 3 must
+be aligned to tiling (128)"), while its own pipeline copies such blocks.
 
-Bit-identity with the dense kernel is by construction: the kernel *body* is
-literally `decode_attention._kernel` (imported, not copied) with
-`block_k = page_size` — on identical KV contents the two kernels execute
-the same sequence of per-block operations, so outputs are bit-equal
-(asserted in `tests/test_serving_paged.py`).
+``ppc`` adapts to the shapes (`pages_per_block`): the most pages with
+``bk <= BLOCK_K`` (the dense kernel's default block), no more than the
+table holds, and an f32 ``[R, bk]`` score block within `SCORE_BYTES` —
+decode (R = g) and 64-token waves (R = 64 * g) at page 16 both take 16.
+
+Bit-identity with the dense kernel is by construction: each compute block
+runs `decode_attention.block_update` (imported, not copied) over its ppc
+pages in order, so on identical KV contents this kernel equals
+``decode_attention(..., block_k=bk)`` bit for bit (asserted in
+`tests/test_serving_paged.py`).
 
 Query windows (``q_rows > 1``) carry over from the dense kernel unchanged:
 the q block holds R = q_rows * g (window, group)-row-major rows per KV
@@ -39,12 +56,13 @@ pool view via `models.layers.gather_kv_pages`.
 
 Block-table safety contract (any q_rows >= 1): entries at or past a
 request's last valid block may point anywhere (the engine points them at
-the shared garbage page) — with `block_skip=True` they are clamped away,
-and with `block_skip=False` their scores are masked to -inf by `lens`, so
-either way they never reach the output.  Window rows extend the contract
-forward in time: row r masks everything past its own absolute position, so
-table entries covering positions written for LATER rows of the same window
-(or garbage beyond the window) never leak backward into row r.
+the shared garbage page).  With `block_skip=True` they are never read; with
+`block_skip=False` (the walk-everything oracle) every entry is copied and
+its scores are masked to -inf by `lens`, so either way they never reach the
+output.  Window rows extend the contract forward in time: row r masks
+everything past its own absolute position, so table entries covering
+positions written for LATER rows of the same window (or garbage beyond the
+window) never leak backward into row r.
 """
 from __future__ import annotations
 
@@ -56,18 +74,69 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.decode_attention import _kernel
+from repro.kernels.decode_attention import (BLOCK_K, block_update, finalize,
+                                            init_state)
+
+# VMEM for one KV head's f32 [R, bk] score block: long query windows take
+# fewer pages per compute block
+SCORE_BYTES = 1 << 20
 
 
-def _paged_kernel(lens_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, page_size, num_blocks,
-                  block_skip, q_rows=1):
-    # tables_ref is consumed exclusively by the index_map (the DMA source
-    # address); the arithmetic is the dense kernel's, block_k = page_size.
-    del tables_ref
-    _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-            block_k=page_size, num_kb=num_blocks, block_skip=block_skip,
-            q_rows=q_rows)
+def pages_per_block(page_size: int, max_blocks: int, rows: int) -> int:
+    """Pages per compute block for a table of `max_blocks` pages of
+    `page_size` and R = `rows` query rows per KV head."""
+    return max(1, min(BLOCK_K // page_size, max_blocks,
+                      SCORE_BYTES // (4 * rows * page_size)))
+
+
+def _pages(lens, page_size, max_blocks, block_skip):
+    """Table entries each slot reads: its live pages, or all of them."""
+    if not block_skip:
+        return jnp.full_like(lens, max_blocks)
+    return jnp.minimum(pl.cdiv(lens, page_size), max_blocks)
+
+
+def _schedule(lens, *, page_size, ppc, max_blocks, block_skip):
+    """The grid's steps: every slot's compute blocks, slot after slot (at
+    least one per slot, so each output block is written).  Returns (steps,
+    slot of each step, block of each step); the arrays are padded to the
+    largest schedule, and the grid runs only the first `steps`."""
+    b = lens.shape[0]
+    nblk = jnp.maximum(pl.cdiv(
+        _pages(lens, page_size, max_blocks, block_skip), ppc), 1)
+    # compares and sums, not a cumsum and a gather: a few fused XLA ops
+    i = jnp.arange(b)
+    ends = jnp.sum(jnp.where(i[None, :] <= i[:, None], nblk[None, :], 0),
+                   axis=1)
+    step = jnp.arange(b * pl.cdiv(max_blocks, ppc), dtype=jnp.int32)
+    past = step[:, None] >= ends[None, :]
+    slot = jnp.minimum(jnp.sum(past, axis=1, dtype=jnp.int32), b - 1)
+    block = step - jnp.sum(jnp.where(past, nblk[None, :], 0), axis=1)
+    return ends[-1], slot, block
+
+
+def _paged_kernel(slot_ref, block_ref, lens_ref, tables_ref, q_ref, *refs,
+                  page_size, ppc, max_blocks, block_skip, q_rows):
+    k_refs, v_refs = refs[:ppc], refs[ppc:2 * ppc]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * ppc:]
+    step = pl.program_id(0)
+    slot, n = slot_ref[step], block_ref[step]
+    length = lens_ref[slot]
+    start = n * ppc * page_size
+    pages = _pages(length, page_size, max_blocks, block_skip)
+
+    pl.when(n == 0)(lambda: init_state(acc_ref, m_ref, l_ref))
+    compute = functools.partial(
+        block_update, q_ref, k_refs, v_refs, acc_ref, m_ref, l_ref,
+        start=start, length=length, q_rows=q_rows)
+    if block_skip:
+        # only a slot of length 0 has a step past its length: its output
+        # stays zero, as the dense kernel's does
+        pl.when(start < length)(compute)
+    else:
+        compute()
+    last = jnp.maximum(pl.cdiv(pages, ppc), 1) - 1
+    pl.when(n == last)(lambda: finalize(o_ref, acc_ref, l_ref))
 
 
 @functools.partial(
@@ -88,35 +157,40 @@ def paged_decode_attention(
     b, nkv, g, hd = q.shape
     assert g % q_rows == 0, (g, q_rows)
     page_size = k_pages.shape[1]
-    num_blocks = tables.shape[1]
+    max_blocks = tables.shape[1]
+    ppc = pages_per_block(page_size, max_blocks, g)
     lens1 = lens.astype(jnp.int32).reshape(b)
-    tables1 = tables.astype(jnp.int32).reshape(b, num_blocks)
+    tables1 = tables.astype(jnp.int32).reshape(b, max_blocks)
+    steps, slot, block = _schedule(lens1, page_size=page_size, ppc=ppc,
+                                   max_blocks=max_blocks,
+                                   block_skip=block_skip)
 
-    def q_index(i, kb, lens_ref, tables_ref):
-        return (i, 0, 0, 0)
+    def q_index(s, slot_ref, block_ref, lens_ref, tables_ref):
+        return (slot_ref[s], 0, 0, 0)
 
-    def kv_index(i, kb, lens_ref, tables_ref):
-        if block_skip:
-            # clamp to the request's last valid logical block; repeated
-            # physical indices let the pipeline skip the redundant fetch
-            last = jnp.maximum(pl.cdiv(lens_ref[i], page_size) - 1, 0)
-            kb = jnp.minimum(kb, last)
-        return (tables_ref[i, kb], 0, 0, 0)
+    def page_index(j):
+        # page j of the step's compute block; past the slot's last page
+        # (the tail of its last block) the last page again, which the
+        # position mask hides
+        def index(s, slot_ref, block_ref, lens_ref, tables_ref):
+            i = slot_ref[s]
+            last = jnp.maximum(_pages(lens_ref[i], page_size, max_blocks,
+                                      block_skip), 1) - 1
+            return (tables_ref[i, jnp.minimum(block_ref[s] * ppc + j, last)],
+                    0, 0, 0)
+        return index
 
-    # blocks as in the dense kernel: all KV heads of one page per grid step
-    grid = (b, num_blocks)
-    kernel = functools.partial(_paged_kernel, page_size=page_size,
-                               num_blocks=num_blocks, block_skip=block_skip,
+    kernel = functools.partial(_paged_kernel, page_size=page_size, ppc=ppc,
+                               max_blocks=max_blocks, block_skip=block_skip,
                                q_rows=q_rows)
     sq = pl.Squeezed()
+    page_specs = [pl.BlockSpec((sq, page_size, nkv, hd), page_index(j))
+                  for j in range(ppc)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((sq, nkv, g, hd), q_index),
-            pl.BlockSpec((sq, page_size, nkv, hd), kv_index),
-            pl.BlockSpec((sq, page_size, nkv, hd), kv_index),
-        ],
+        num_scalar_prefetch=4,
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((sq, nkv, g, hd), q_index),
+                  *page_specs, *page_specs],
         out_specs=pl.BlockSpec((sq, nkv, g, hd), q_index),
         scratch_shapes=[
             pltpu.VMEM((nkv, g, hd), jnp.float32),
@@ -129,11 +203,12 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # a slot's steps revisit its output block
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="papi_paged_decode_attention",
-    )(lens1, tables1, q, k_pages, v_pages)
+    )(slot, block, lens1, tables1, q, *[k_pages] * ppc, *[v_pages] * ppc)
 
 
 def paged_decode_attention_sharded(

@@ -342,8 +342,8 @@ def gather_kv_pages(pages: jax.Array, tables: jax.Array) -> jax.Array:
     [b, max_blocks * page, nkv, hd].  This is the XLA decode path for the
     paged cache — since the Pallas kernels went windowed it is OFF the
     jitted hot path under attn_impl("pim") and survives as the tested
-    bit-identity oracle (the paged kernel performs the same gather inside
-    its index_map without ever building this view)."""
+    bit-identity oracle (the paged kernel reads the live pages through
+    the block table without ever building this view)."""
     b, nblk = tables.shape
     _, page, nkv, hd = pages.shape
     g = jnp.take(pages, tables, axis=0)          # [b, nblk, page, nkv, hd]

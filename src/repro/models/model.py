@@ -423,10 +423,10 @@ def _decode_attention(q, k_cache, v_cache, pos, tables=None):
 
     Under `attn_impl("pim")` EVERY case — any t >= 1, dense or paged — runs
     the Pallas flash-decode kernel (the Attn-PIM unit): dense streams the
-    per-slot slab, paged resolves pages inside the kernel's block-table
-    index_map, so `gather_kv_pages` never appears in a jitted program on
-    this path.  The XLA softmax path (with the page gather when paged)
-    remains as the tested oracle."""
+    per-slot slab, paged reads each slot's live pages through the block
+    table inside the kernel, so `gather_kv_pages` never appears in a jitted
+    program on this path.  The XLA softmax path (with the page gather when
+    paged) remains as the tested oracle."""
     t = q.shape[1]
     if L.current_attn_impl() == "pim":
         if tables is not None:

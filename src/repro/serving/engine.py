@@ -51,8 +51,9 @@ on finish).  A single request may span nearly the whole pool — context
 length is bounded by pooled memory, not a per-slot slab.  Decode attention
 either gathers pages into the XLA path or — with ``attn_pim=True`` — runs
 the block-table Pallas kernel (`kernels.paged_decode_attention`), which
-resolves pages inside its index_map for ANY TLP (plain decode, speculative
-verify windows, chunked-prefill waves): `gather_kv_pages` never appears in
+reads each slot's live pages through the block table for ANY TLP (plain
+decode, speculative verify windows, chunked-prefill waves), whatever
+`max_blocks` is: `gather_kv_pages` never appears in
 a jitted program under attn_pim.  Token streams are identical to the
 dense engine on any workload both can hold (tested).  Per-iteration pool
 stats (pages used/free, watermark, fragmentation) ride on `IterStats`.
@@ -447,9 +448,10 @@ class PapiEngine:
             # max_blocks bounds the block-table width: per-request context
             # AND the width of the XLA path's gathered KV view (which pays
             # for max_blocks * page_size per slot per step regardless of
-            # live length).  Default None = the whole usable pool, i.e. one
-            # request may span nearly all of it; cap it when serving many
-            # short requests from a large pool.
+            # live length).  The paged kernel (attn_pim) reads only each
+            # slot's live pages, whatever the width.  Default None = the
+            # whole usable pool, i.e. one request may span nearly all of
+            # it; cap it when serving many short requests from a large pool.
             self.kv = PagedKVManager(num_pages=num_pages, page_size=page_size,
                                      max_slots=max_slots,
                                      max_blocks=max_blocks)
@@ -688,6 +690,10 @@ class PapiEngine:
                 # requests (their tail + the final event)
                 for s in self.active_slots:
                     req = self.slot_req[s]
+                    if req is None:
+                        # cancelled by the consumer while an earlier slot's
+                        # tokens were yielded: _drain_events finishes it
+                        continue
                     done = (req.done if isinstance(req, _ResumedRequest)
                             else [])
                     full = list(done) + self.slot_tokens[s]

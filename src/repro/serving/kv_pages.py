@@ -35,7 +35,8 @@ host — the standard padded-batch trade), and their KV writes must land
 *somewhere* that no live request owns.  Every unmapped block-table entry
 points at page 0, so garbage writes collide harmlessly there; live requests
 never reference it (entries past a request's mapped prefix are either
-clamped away by the paged kernel's index_map or masked by `cache_len`).
+never read by the paged kernel, which reads only live pages, or masked by
+`cache_len`).
 
 Invariants (property-tested in `tests/test_kv_pages.py`):
   * a physical page is never mapped to two owners at once;

@@ -179,6 +179,66 @@ def test_decode_attention_window_matches_xla_layer_path():
 
 
 # ---------------------------------------------------------------------------
+# paged_decode_attention: compute blocks from shapes, grid from live pages
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("page,max_blocks,rows,ppc", [
+    (16, 256, 7, 16),       # qwen2-0.5b decode: bk = 256
+    (16, 256, 64 * 7, 16),  # its 64-token waves: a [448, 256] f32 score
+    (16, 256, 512 * 7, 4),  # 512-token waves: the score budget binds
+    (32, 6, 8, 6),          # a table shorter than a full block
+    (512, 8, 7, 1),         # pages longer than a block: one per block
+])
+def test_pages_per_block_adapts_to_shapes(page, max_blocks, rows, ppc):
+    from repro.kernels.paged_decode_attention import (SCORE_BYTES,
+                                                      pages_per_block)
+    assert pages_per_block(page, max_blocks, rows) == ppc
+    if ppc > 1:
+        assert 4 * rows * ppc * page <= SCORE_BYTES
+
+
+@pytest.mark.parametrize("block_skip", [True, False])
+def test_paged_schedule_walks_only_live_blocks(block_skip):
+    """The grid's steps are each slot's compute blocks in order: exactly
+    cdiv(lens, bk) of them with skipping (one for an empty slot, whose
+    output stays zero), every table entry without."""
+    from repro.kernels.paged_decode_attention import _schedule
+    page, ppc, max_blocks = 16, 4, 10                   # bk = 64
+    lens = jnp.asarray([1, 64, 65, 0, 160], jnp.int32)
+    steps, slot, block = _schedule(lens, page_size=page, ppc=ppc,
+                                   max_blocks=max_blocks,
+                                   block_skip=block_skip)
+    per_slot = [1, 1, 2, 1, 3] if block_skip else [3] * 5
+    assert int(steps) == sum(per_slot)
+    assert slot.shape == block.shape == (5 * 3,)
+    want = [(i, n) for i, k in enumerate(per_slot) for n in range(k)]
+    got = list(zip(np.asarray(slot)[:int(steps)].tolist(),
+                   np.asarray(block)[:int(steps)].tolist()))
+    assert got == want
+
+
+def test_paged_empty_slot_reads_zero():
+    """lens == 0 (which the engine never produces) gives zeros, as the
+    dense kernel's skipping does."""
+    from repro.kernels.paged_decode_attention import paged_decode_attention
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(ks[0], (2, 2, 4, 32), jnp.float32)
+    kp = jax.random.normal(ks[1], (5, 16, 2, 32), jnp.float32)
+    vp = jax.random.normal(ks[2], (5, 16, 2, 32), jnp.float32)
+    tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    out = paged_decode_attention(q, kp, vp, jnp.asarray([0, 20], jnp.int32),
+                                 tables, interpret=True)
+    assert not np.asarray(out[0]).any()
+    dense = decode_attention(q, jnp.stack([kp[1:3].reshape(32, 2, 32),
+                                           kp[3:5].reshape(32, 2, 32)]),
+                             jnp.stack([vp[1:3].reshape(32, 2, 32),
+                                        vp[3:5].reshape(32, 2, 32)]),
+                             jnp.asarray([0, 20], jnp.int32), block_k=32,
+                             interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(dense))
+
+
+# ---------------------------------------------------------------------------
 # fc_gemv
 # ---------------------------------------------------------------------------
 

@@ -7,8 +7,9 @@ The contract under test (ISSUE 3 + ISSUE 5 acceptance):
   * a request whose prompt+generation exceeds the dense per-slot capacity
     completes under the paged layout (pooled pages, no uniform slot cap);
   * the paged flash-decode kernel is bit-identical to the dense kernel on
-    identical KV contents (same body, block_k = page_size) — for every
-    query-window width t (plain decode, TLP>1 verify, chunk waves);
+    identical KV contents (same block update, block_k = the paged kernel's
+    compute block) — for every query-window width t (plain decode, TLP>1
+    verify, chunk waves) — and never reads a page past a slot's length;
   * under attn_pim the WINDOWED kernel serves speculative verify and
     chunked prefill too, token-identically to the XLA engines, and
     `gather_kv_pages` never traces (poison-tested);
@@ -297,40 +298,93 @@ def test_long_prompts_complete_untruncated(small_model, kv_layout):
         assert results[i].tokens == oneshot[i].tokens
 
 
-@pytest.mark.parametrize("t", [1, 2, 4])
-def test_paged_kernel_bit_identical_to_dense_kernel(t):
-    """Identical KV contents scattered across a shuffled page pool: the
-    paged kernel (block-table index_map) must be BIT-identical to the
-    dense kernel at block_k = page_size — the body is the same code.
-    Holds for every query-window width: t=1 plain decode, t=2, and a
-    spec-window t=4 (the windowed rows share the body's intra-window
-    mask)."""
-    b, nkv, g, hd, page, nblk = 3, 2, 4, 64, 32, 6
-    S = page * nblk
-    num_pages = b * nblk + 1
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (b, nkv, t * g, hd), jnp.float32)
-    kd = jax.random.normal(ks[1], (b, S, nkv, hd), jnp.float32)
-    vd = jax.random.normal(ks[2], (b, S, nkv, hd), jnp.float32)
-    lens = jnp.asarray([33, S, 7], jnp.int32)   # ragged: mid, full, tiny
-
-    rng = np.random.default_rng(0)
-    tables = rng.permutation(np.arange(1, num_pages)).reshape(b, nblk)
-    kp = np.zeros((num_pages, page, nkv, hd), np.float32)
+def _paged_copy(kd, vd, tables, page):
+    """A page pool holding the dense cache's first `nblk` blocks of each
+    slot at the pages its table names (page 0 and others left zero)."""
+    b, nblk = tables.shape
+    kp = np.zeros((b * nblk + 1, page) + kd.shape[2:], np.float32)
     vp = np.zeros_like(kp)
     for i in range(b):
         for blk in range(nblk):
             kp[tables[i, blk]] = np.asarray(kd)[i, blk * page:(blk + 1) * page]
             vp[tables[i, blk]] = np.asarray(vd)[i, blk * page:(blk + 1) * page]
+    return kp, vp
+
+
+# (page_size, max_blocks, lens): compute blocks of bk = 6 * 32 = 192
+# positions (the table holds 6 pages), and of 16 * 16 = 256 positions over
+# a table of 20 pages, which 16 does not divide; lens end mid-page, on the
+# table's end, on a compute-block boundary, one past it, and at 1 (a parked
+# slot)
+BK_CASES = {192: (32, 6, [33, 192, 7]),
+            256: (16, 20, [37, 256, 257, 1, 320])}
+
+
+@pytest.mark.parametrize("bk", sorted(BK_CASES))
+@pytest.mark.parametrize("t", [1, 2, 4])
+def test_paged_kernel_bit_identical_to_dense_kernel(t, bk):
+    """Identical KV contents scattered across a shuffled page pool: the
+    paged kernel (live pages, `ppc` per compute block) must be
+    BIT-identical to the dense kernel at block_k = bk — each compute block
+    runs the same `block_update`.  Holds for every query-window width: t=1
+    plain decode, t=2, and a spec-window t=4 (the windowed rows share the
+    body's intra-window mask), skipping dead pages or walking them all."""
+    from repro.kernels.paged_decode_attention import pages_per_block
+    page, nblk, lens = BK_CASES[bk]
+    assert pages_per_block(page, nblk, t * 4) * page == bk
+    b, nkv, g, hd = len(lens), 2, 4, 64
+    S = -(-nblk * page // bk) * bk        # the dense cache in whole blocks
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (b, nkv, t * g, hd), jnp.float32)
+    kd = jax.random.normal(ks[1], (b, S, nkv, hd), jnp.float32)
+    vd = jax.random.normal(ks[2], (b, S, nkv, hd), jnp.float32)
+    lens = jnp.asarray([max(n, t) for n in lens], jnp.int32)
+
+    rng = np.random.default_rng(0)
+    tables = rng.permutation(np.arange(1, b * nblk + 1)).reshape(b, nblk)
+    kp, vp = _paged_copy(kd, vd, tables, page)
 
     for skip in (True, False):
-        want = decode_attention(q, kd, vd, lens, block_k=page,
+        want = decode_attention(q, kd, vd, lens, block_k=bk,
                                 interpret=True, block_skip=skip, q_rows=t)
         got = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
                                      lens, jnp.asarray(tables),
                                      interpret=True, block_skip=skip,
                                      q_rows=t)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("t", [1, 3])
+def test_paged_kernel_never_reads_a_dead_page(t):
+    """Every pool page outside the slots' live prefixes holds NaN, the
+    garbage page too, and the tables point past each prefix at NaN pages:
+    with block_skip=True the output is finite and equal to that over a
+    clean pool.  A dead page that reached the math (a NaN V row times a
+    masked p = 0) would make it NaN."""
+    page, nblk, lens = BK_CASES[256]
+    b, nkv, g, hd = len(lens), 2, 4, 64
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (b, nkv, t * g, hd), jnp.float32)
+    kp = np.asarray(jax.random.normal(ks[1], (b * nblk + 1, page, nkv, hd)))
+    vp = np.asarray(jax.random.normal(ks[2], kp.shape))
+    lens = jnp.asarray([max(n, t) for n in lens], jnp.int32)
+    tables = np.random.default_rng(1).permutation(
+        np.arange(1, b * nblk + 1)).reshape(b, nblk)
+    live = np.zeros(len(kp), bool)
+    for i, n in enumerate(np.asarray(lens)):
+        live[tables[i, :-(-n // page)]] = True
+        if i % 2:                         # past the prefix: garbage page
+            tables[i, -(-n // page):] = 0
+    kn, vn = kp.copy(), vp.copy()
+    kn[~live] = np.nan
+    vn[~live] = np.nan
+    args = (lens, jnp.asarray(tables))
+    clean = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+                                   *args, interpret=True, q_rows=t)
+    got = paged_decode_attention(q, jnp.asarray(kn), jnp.asarray(vn),
+                                 *args, interpret=True, q_rows=t)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
 
 
 def test_paged_windowed_kernel_matches_gather_oracle():

@@ -90,3 +90,19 @@ def test_paged_decode_attention_compiles(one_chip, q_rows):
         _spec(one_chip, (SLOTS,), jnp.int32),
         _spec(one_chip, (SLOTS, blocks), jnp.int32))
     assert "papi_paged_decode_attention" in calls
+
+
+@pytest.mark.parametrize("q_rows", [1, 64])
+def test_paged_decode_attention_compiles_at_the_cell_shapes(one_chip, q_rows):
+    """qwen2-0.5b.creative: 32 slots, tables of 256 pages of 16, a pool of
+    2,049 pages; decode (q_rows 1) and the 64-token mixed waves (448 score
+    rows per KV head), both at 16 pages per compute block."""
+    kv = _spec(one_chip, (2049, 16, NKV, HD))
+    calls = _compiled_kernels(
+        lambda q, k, v, lens, tables: paged_decode_attention(
+            q, k, v, lens, tables, q_rows=q_rows, interpret=False),
+        _spec(one_chip, (32, NKV, q_rows * G, HD)), kv, kv,
+        _spec(one_chip, (32,), jnp.int32),
+        _spec(one_chip, (32, 256), jnp.int32))
+    assert len(calls.splitlines()) == 1
+    assert "papi_paged_decode_attention" in calls
