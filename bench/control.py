@@ -27,7 +27,8 @@ def readings(cell_spec, seed: int, seconds: float) -> dict:
     config, cell = cell_spec.config, cell_spec.cell
     cfg = system.model_config(config)
     eng = system.build_engine(
-        cfg, system.program_params(cfg, weights.model(config, seed)), cell)
+        cfg, system.program_params(config, cfg,
+                                    weights.model(config, seed)), cell)
     warm = run.warm_up(eng, cell, config["vocab_size"])
     arrivals = loadgen.schedule(cell_spec.traffic, float(cell["rate_per_s"]),
                                 float(cell["ramp_s"]) + seconds,

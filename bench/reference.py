@@ -1,15 +1,12 @@
-"""Plain float32 forward pass of a dense decoder (Qwen2 / Llama layer
-equations), written from the published description and independent of the
-program under test.
+"""Plain float32 forward pass of a model, written from the published
+description and independent of the program under test.
 
-    h0 = embed[tokens]
-    per layer:  a = rmsnorm(h) * input_norm
-                q, k, v = a Wq + bq, a Wk + bk, a Wv + bv, RoPE on q and k
-                  (rotate-half form, inverse frequencies theta^(-2i/hd))
-                h += softmax(q k^T / sqrt(hd), causal, GQA) v Wo
-                m = rmsnorm(h) * post_norm
-                h += (silu(m Wgate) * (m Wup)) Wdown
-    logits = (rmsnorm(h) * final_norm) embed^T        (tied LM head)
+The layer and logit equations are the configuration's family's
+(bench/families/<family>.py: `reference_layer`, `reference_logits`); this
+module embeds the tokens (every family's globals hold its input embedding
+as `embed` [V, d]), runs the layers in order and compares the served
+tokens.  It keeps the helpers that families share: `_rmsnorm`, `_rope` and
+`_attention`.
 
 It runs layer by layer, making each layer's weights again from the seed
 (bench/weights.py), so that it fits beside nothing else on the chip.  Every
@@ -24,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import spec
 from bench import weights as W
 
 QUERY_BLOCK = 512
@@ -83,28 +81,10 @@ def _attention(q, k, v, mm):
     return jnp.concatenate(outs, 0)
 
 
-def _layer(config: dict, mm, lw: dict, h):
-    m = W.dims(config)
-    d, nh, nkv, hd = m["d"], m["nh"], m["nkv"], m["hd"]
-    eps, theta = config["rms_norm_eps"], config["rope_theta"]
-    t = h.shape[0]
-    a = _rmsnorm(h, lw["input_norm"], eps)
-    q = mm(a, lw["q"].reshape(d, nh * hd)).reshape(t, nh, hd)
-    k = mm(a, lw["k"].reshape(d, nkv * hd)).reshape(t, nkv, hd)
-    v = mm(a, lw["v"].reshape(d, nkv * hd)).reshape(t, nkv, hd)
-    if "q_bias" in lw:
-        q, k, v = q + lw["q_bias"], k + lw["k_bias"], v + lw["v_bias"]
-    q, k = _rope(q, theta), _rope(k, theta)
-    att = _attention(q, k, v, mm).reshape(t, nh * hd)
-    h = h + mm(att, lw["o"].reshape(nh * hd, d))
-    x = _rmsnorm(h, lw["post_norm"], eps)
-    act = jax.nn.silu(mm(x, lw["gate"])) * mm(x, lw["up"])
-    return h + mm(act, lw["down"])
-
-
 @functools.lru_cache(maxsize=None)
 def _fns(frozen: str, kind: str):
     config = W.thaw(frozen)
+    family = spec.family(config)
     mm = MATMULS[kind]
     f32 = functools.partial(jax.tree.map, lambda x: x.astype(jnp.float32))
 
@@ -117,14 +97,12 @@ def _fns(frozen: str, kind: str):
         return e[tokens]
 
     @jax.jit
-    def layer(lw, h):
-        return _layer(config, mm, f32(lw), h)
+    def layer(lw, h, l):
+        return family.reference_layer(config, mm, f32(lw), h, l)
 
     @jax.jit
     def logits(g, h):
-        g = f32(g)
-        x = _rmsnorm(h, g["final_norm"], config["rms_norm_eps"])
-        return mm(x, g["embed"].T)
+        return family.reference_logits(config, mm, f32(g), h)
 
     return embed, layer, logits
 
@@ -173,7 +151,7 @@ def served_gaps(config: dict, seed: int, prompts: list[list[int]],
             lw = W.layer(config, seed, l)
             for kind in kinds:
                 layer = _fns(frozen, kind)[1]
-                hs[kind] = [layer(lw, h) for h in hs[kind]]
+                hs[kind] = [layer(lw, h, jnp.int32(l)) for h in hs[kind]]
             del lw
         got, alt = [], []
         for i, (p, t) in enumerate(zip(prompts, served)):

@@ -321,7 +321,8 @@ def run(args) -> dict:
     from bench import weights
 
     cfg = system.model_config(config)
-    params = system.program_params(cfg, weights.model(config, args.seed))
+    params = system.program_params(config, cfg,
+                                   weights.model(config, args.seed))
     eng = system.build_engine(cfg, params, cell)
     warm = warm_up(eng, cell, config["vocab_size"])
     arrivals = loadgen.schedule(traffic, float(cell["rate_per_s"]),

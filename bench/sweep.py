@@ -40,7 +40,8 @@ def main(argv=None) -> int:
     enable_compile_cache()
     config, cell = cell_spec.config, dict(cell_spec.cell, ramp_s=0.0)
     cfg = system.model_config(config)
-    params = system.program_params(cfg, weights.model(config, args.seed))
+    params = system.program_params(config, cfg,
+                                   weights.model(config, args.seed))
     for rate in [float(r) for r in args.rates.split(",")]:
         eng = system.build_engine(cfg, params, cell)
         run.warm_up(eng, cell, config["vocab_size"])

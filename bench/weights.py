@@ -1,4 +1,4 @@
-"""Seeded random weights of a dense decoder, made on the device.
+"""Seeded random weights of a model, made on the device.
 
 The benchmark makes the weights itself, so that the plain reference can
 make the same numbers again, layer by layer, without taking anything from
@@ -6,67 +6,25 @@ the program under test.  Every tensor's key is derived from the seed, the
 layer index and the tensor's name alone, so `layer(cfg, seed, l)` gives
 bit for bit the slice `l` of `model(cfg, seed)["layers"]`.
 
-Names and shapes (d model width, nh/nkv query/KV heads of hd, f MLP width,
-V vocabulary):
-
-    embed [V, d] (tied LM head), final_norm [d]
-    per layer: input_norm [d], q [d, nh, hd], k/v [d, nkv, hd],
-               q_bias [nh, hd], k_bias/v_bias [nkv, hd] (with attention
-               bias), o [nh, hd, d], post_norm [d], gate/up [d, f],
-               down [f, d]
+The names, shapes, laws and scales of the tensors come from the
+configuration's family (bench/families/<family>.py): `layer_laws` for the
+tensors of one layer, `global_laws` for those outside the layers.
 """
 from __future__ import annotations
 
 import functools
 import json
-import math
 
 import jax
 import jax.numpy as jnp
 
+from bench import spec
+
 _DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
-
-
-def dims(config: dict) -> dict:
-    d = config["hidden_size"]
-    nh = config["num_attention_heads"]
-    return dict(d=d, nh=nh, nkv=config["num_key_value_heads"],
-                hd=config.get("head_dim") or d // nh,
-                f=config["intermediate_size"], V=config["vocab_size"],
-                L=config["num_hidden_layers"])
 
 
 def serve_dtype(config: dict):
     return _DTYPES[config["torch_dtype"]]
-
-
-def layer_laws(config: dict) -> dict[str, tuple[tuple[int, ...], str, float]]:
-    """name -> (shape, law, scale) for one layer.  Projections into the
-    residual stream are scaled down by sqrt(2 L), the usual depth-scaled
-    init, with L the published depth (so a model cut in depth holds the
-    first layers of the whole one); norm weights scatter around 1 and
-    biases around 0, so that both reach the comparison with the
-    reference."""
-    m = dims(config)
-    d, nh, nkv, hd, f = m["d"], m["nh"], m["nkv"], m["hd"], m["f"]
-    depth = config.get("published", {}).get("num_hidden_layers", m["L"])
-    res = d ** -0.5 / math.sqrt(2 * depth)
-    laws = {
-        "input_norm": ((d,), "around_one", 0.05),
-        "q": ((d, nh, hd), "normal", d ** -0.5),
-        "k": ((d, nkv, hd), "normal", d ** -0.5),
-        "v": ((d, nkv, hd), "normal", d ** -0.5),
-        "o": ((nh, hd, d), "normal", res),
-        "post_norm": ((d,), "around_one", 0.05),
-        "gate": ((d, f), "normal", d ** -0.5),
-        "up": ((d, f), "normal", d ** -0.5),
-        "down": ((f, d), "normal", res),
-    }
-    if config["attention_bias"]:
-        laws["q_bias"] = ((nh, hd), "normal", 0.1)
-        laws["k_bias"] = ((nkv, hd), "normal", 0.1)
-        laws["v_bias"] = ((nkv, hd), "normal", 0.1)
-    return laws
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -86,23 +44,24 @@ def _tensor(key, name: str, shape, law: str, scale: float, dtype):
     return x.astype(dtype)
 
 
+def _tensors(laws: dict, key, dtype) -> dict:
+    return {name: _tensor(key, name, shape, law, scale, dtype)
+            for name, (shape, law, scale) in laws.items()}
+
+
 def _layer(config: dict, key, l, dtype) -> dict:
     lk = jax.random.fold_in(key, 1000 + l)
-    return {name: _tensor(lk, name, shape, law, scale, dtype)
-            for name, (shape, law, scale) in layer_laws(config).items()}
+    return _tensors(spec.family(config).layer_laws(config), lk, dtype)
 
 
 def _globals(config: dict, key, dtype) -> dict:
-    m = dims(config)
-    return {"embed": _tensor(key, "embed", (m["V"], m["d"]), "normal", 0.02,
-                             dtype),
-            "final_norm": _tensor(key, "final_norm", (m["d"],), "around_one",
-                                  0.05, dtype)}
+    return _tensors(spec.family(config).global_laws(config), key, dtype)
 
 
 def model(config: dict, seed: int) -> dict:
     """All weights in the served dtype, in one jitted call on the default
-    device: {"embed", "final_norm", "layers": {name: [L, ...]}}."""
+    device: the family's global tensors by name, and "layers": {name:
+    [L, ...]}."""
     dtype = serve_dtype(config)
     @jax.jit
     def make(key):

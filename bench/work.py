@@ -2,22 +2,22 @@
 
 Counts are of the work a request needs, not of what the program happens to
 compute: padded rows, walked-but-dead cache blocks and copies of the cache
-are left out.  All tensors are 2 bytes (bf16) unless said otherwise.
+are left out.  All tensors are 2 bytes (bf16) unless said otherwise.  What
+a model's layers hold is its family's (bench/families/<family>.py), so the
+counts of a model are the family's; this module keeps the signatures that
+the metric readers call.
 """
 from __future__ import annotations
 
-from bench.weights import dims
+from bench import spec
 
 BYTES = 2
 
 
 def fc_shapes(config: dict) -> list[tuple[str, int, int]]:
-    """(name, K, N) of the seven FC projections of one layer, in the order
-    the program runs them."""
-    m = dims(config)
-    d, nh, nkv, hd, f = m["d"], m["nh"], m["nkv"], m["hd"], m["f"]
-    return [("q", d, nh * hd), ("k", d, nkv * hd), ("v", d, nkv * hd),
-            ("o", nh * hd, d), ("gate", d, f), ("up", d, f), ("down", f, d)]
+    """(name, K, N) of the FC projections of one layer, in the order the
+    program runs them."""
+    return spec.family(config).fc_shapes(config)
 
 
 def fc_call(m_rows: int, k: int, n: int) -> tuple[float, float]:
@@ -32,33 +32,18 @@ def layer_matmul_params(config: dict) -> int:
 
 def attention_work(config: dict, ctx: int, q_rows: int = 1) -> tuple[float, float]:
     """(FLOPs, bytes) of decode attention, all layers, for `q_rows` query
-    rows of one sequence over `ctx` live KV positions: scores and weighted
-    sum (4 ctx nh hd per row), the live K and V read once, q read and the
-    output written."""
-    m = dims(config)
-    flops = 4.0 * q_rows * ctx * m["nh"] * m["hd"]
-    nbytes = (ctx * 2 * m["nkv"] * m["hd"] + 2 * q_rows * m["nh"] * m["hd"]) \
-        * BYTES
-    return m["L"] * flops, m["L"] * nbytes
+    rows of one sequence over `ctx` live KV positions."""
+    return spec.family(config).attention_work(config, ctx, q_rows)
 
 
 def token_flops(config: dict, ctx: int, logits: bool) -> float:
     """Model FLOPs of one token at a position that sees `ctx` positions
-    (itself included): 2 x layer matmul parameters, attention over the
-    context, and the LM head where the token's logits are needed."""
-    m = dims(config)
-    f = 2.0 * m["L"] * layer_matmul_params(config)
-    f += 4.0 * m["L"] * m["nh"] * m["hd"] * ctx
-    if logits:
-        f += 2.0 * m["d"] * m["V"]
-    return f
+    (itself included), with the LM head where the token's logits are
+    needed."""
+    return spec.family(config).token_flops(config, ctx, logits)
 
 
 def prompt_flops(config: dict, prompt_len: int) -> float:
-    """Model FLOPs of a whole prompt: every position with its causal
-    context, and the LM head once, for the first output token."""
-    m = dims(config)
-    n = prompt_len
-    f = 2.0 * m["L"] * layer_matmul_params(config) * n
-    f += 4.0 * m["L"] * m["nh"] * m["hd"] * n * (n + 1) / 2
-    return f + 2.0 * m["d"] * m["V"]
+    """Model FLOPs of a whole prompt, and the LM head once, for the first
+    output token."""
+    return spec.family(config).prompt_flops(config, prompt_len)

@@ -12,9 +12,10 @@ import numpy as np
 from bench import reference, system, weights
 
 WHOLE = {
-    "name": "small", "hidden_size": 128, "intermediate_size": 256,
-    "num_hidden_layers": 12, "num_attention_heads": 4,
-    "num_key_value_heads": 2, "head_dim": 32, "vocab_size": 64,
+    "name": "small", "family": "dense", "hidden_size": 128,
+    "intermediate_size": 256, "num_hidden_layers": 12,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
+    "vocab_size": 64,
     "hidden_act": "silu", "rms_norm_eps": 1e-05, "rope_theta": 10000.0,
     "tie_word_embeddings": True, "attention_bias": True,
     "torch_dtype": "bfloat16", "reduced": []}
@@ -52,7 +53,8 @@ def test_first_layers_are_the_whole_models():
         assert a.keys() == b.keys()
         for k in a:
             assert np.array_equal(np.asarray(a[k]), np.asarray(b[k])), k
-        assert np.array_equal(np.asarray(fa(a, h)), np.asarray(fb(b, h)))
+        assert np.array_equal(np.asarray(fa(a, h, l)),
+                              np.asarray(fb(b, h, l)))
     stacked = weights.model(CUT, 5)["layers"]
     assert stacked["q"].shape[0] == 6
     assert np.array_equal(np.asarray(stacked["down"][5]),

@@ -65,7 +65,8 @@ def test_traced_rehearsal_finds_a_new_metric_file(tmp_path):
 def test_benchmark_json_names_every_file():
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     for c in spec["configs"]:
-        assert (ROOT / c["file"]).exists()
+        family = json.loads((ROOT / c["file"]).read_text())["family"]
+        assert (ROOT / "bench/families" / f"{family}.py").exists()
     for w in spec["workloads"]:
         assert (ROOT / "bench/cells" / f"{w['name']}.json").exists()
         assert (ROOT / "bench/traffic" / f"{w['traffic']}.json").exists()

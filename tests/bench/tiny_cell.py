@@ -18,8 +18,8 @@ for p in (str(ROOT), str(ROOT / "src")):
 WORKLOAD = "tiny.short"
 
 CONFIG = {
-    "name": "tiny", "hidden_size": 64, "intermediate_size": 128,
-    "num_hidden_layers": 2, "num_attention_heads": 4,
+    "name": "tiny", "family": "dense", "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 2, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 512,
     "hidden_act": "silu", "rms_norm_eps": 1e-06, "rope_theta": 10000.0,
     "tie_word_embeddings": True, "attention_bias": True,
@@ -43,8 +43,8 @@ CELL = {
 
 def make_bench(tmp: Path, extra_metrics: dict[str, str] | None = None) -> Path:
     """Write a BENCHMARK.json with the tiny cell under `tmp`, with the
-    real bench/metrics readers (plus `extra_metrics`: name -> source);
-    returns its path."""
+    real bench/metrics readers (plus `extra_metrics`: name -> source) and
+    bench/families; returns its path."""
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     spec["configs"] = [{"name": "tiny", "source": "test",
                         "file": "bench/configs/tiny.json", "reduced": [],
@@ -54,8 +54,9 @@ def make_bench(tmp: Path, extra_metrics: dict[str, str] | None = None) -> Path:
     for m in spec["end_to_end"] + spec["per_layer"]:
         m.pop("workloads", None)
     bench = tmp / "bench"
-    shutil.copytree(ROOT / "bench" / "metrics", bench / "metrics",
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for sub in ("metrics", "families"):
+        shutil.copytree(ROOT / "bench" / sub, bench / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for sub, name, data in (("configs", "tiny", CONFIG),
                             ("traffic", "short", TRAFFIC),
                             ("cells", WORKLOAD, CELL)):
